@@ -1,10 +1,12 @@
 """Shared benchmark infrastructure.
 
 Graphs are synthetic with Table 2-matched statistics (SNAP datasets are not
-redistributable offline — recorded in EXPERIMENTS.md).  ``--scale`` shrinks
-every preset proportionally; timing medians of N repeats after a warmup.
-This container is a single CPU core: absolute times calibrate the *relative*
-story (DBL vs baselines), the TPU story is the §Roofline analysis.
+redistributable offline).  ``--scale`` shrinks every preset proportionally;
+timing medians of N repeats after a warmup.  These benches time whatever
+backend JAX runs on: on a CPU they measure XLA's CPU backend and the Pallas
+interpreter, not the chip, so their numbers compare DBL with its baselines
+on the same host and say nothing about TPU speed.  ``chip_smoke.py`` is
+what runs the served path on a TPU.
 """
 from __future__ import annotations
 

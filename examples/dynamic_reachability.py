@@ -13,6 +13,7 @@ import numpy as np
 from repro.baselines import bbfs
 from repro.core import DBLIndex, make_graph
 from repro.graphs.generators import power_law
+from repro.serve.compile_cache import enable_compile_cache
 from repro.serve.reach_server import ReachabilityServer
 
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--verify", type=int, default=200,
                     help="verify this many queries per round against B-BFS")
     args = ap.parse_args()
+    enable_compile_cache()
 
     src, dst = power_law(args.n, args.m, seed=0)
     g = make_graph(src, dst, args.n,

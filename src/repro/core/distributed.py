@@ -159,9 +159,9 @@ def distributed_insert(idx: DBLIndex, mesh: Mesh, new_src, new_dst,
 # ===================================================================
 def vertex_mesh(shards: int | None = None) -> Mesh:
     """A 1-axis ``"vertex"`` mesh over ``shards`` devices (default: all)."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import auto_mesh
     shards = shards or len(jax.devices())
-    return make_mesh_compat((shards,), (PL.VERTEX_AXIS,))
+    return auto_mesh((shards,), (PL.VERTEX_AXIS,))
 
 
 def vertex_index_shardings(mesh: Mesh, *, il: bool = False) -> DBLIndex:

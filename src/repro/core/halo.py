@@ -62,7 +62,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import bitset
@@ -183,7 +183,7 @@ def _probe_impl(fr, h_send, h_valid, h_hub, hubs, hub_slot, *, mesh,
         front = jax.lax.psum(fr.sum().astype(jnp.int32), ax)
         return cnt[None, :], front, hub_any
 
-    sm = shard_map(shard_body, mesh=mesh, check_rep=False,
+    sm = shard_map(shard_body, mesh=mesh, check_vma=False,
                    in_specs=(P(ax), P(ax, None, None), P(ax, None, None))
                    + _hub_specs(ax, use_hubs),
                    out_specs=(P(ax, None), P(), P()))
@@ -345,7 +345,7 @@ def _regime_impl(x, fr, live, it0, e_slot, e_recv, e_gid, e_valid, e_start,
 
     plane_sp = P(ax, None)
     sm = shard_map(
-        shard_body, mesh=mesh, check_rep=False,
+        shard_body, mesh=mesh, check_vma=False,
         in_specs=(plane_sp, P(ax), P(), P(),
                   plane_sp, plane_sp, plane_sp, plane_sp, plane_sp,
                   plane_sp, P(ax, None, None), P(ax, None, None))

@@ -53,7 +53,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import bitset
@@ -919,7 +919,7 @@ def _halo_propagate_impl(x, frontier, live, e_slot, e_recv, e_gid, e_valid,
         return x, iters
 
     sm = shard_map(
-        shard_body, mesh=mesh, check_rep=False,
+        shard_body, mesh=mesh, check_vma=False,
         in_specs=(plane_sp, vec_sp, rep,
                   plane_sp, plane_sp, plane_sp, plane_sp,
                   P(ax, None, None), P(ax, None, None)),
@@ -977,7 +977,7 @@ def _halo_propagate_min_impl(x, frontier, live, e_slot, e_recv, e_gid,
         return x, iters
 
     sm = shard_map(
-        shard_body, mesh=mesh, check_rep=False,
+        shard_body, mesh=mesh, check_vma=False,
         in_specs=(plane_sp, vec_sp, rep,
                   plane_sp, plane_sp, plane_sp, plane_sp,
                   P(ax, None, None), P(ax, None, None)),
@@ -1036,7 +1036,7 @@ def _halo_propagate_packed_impl(xw, frontier, live, e_slot, e_recv, e_gid,
         return xw, iters
 
     sm = shard_map(
-        shard_body, mesh=mesh, check_rep=False,
+        shard_body, mesh=mesh, check_vma=False,
         in_specs=(plane_sp, vec_sp, rep,
                   plane_sp, plane_sp, plane_sp, plane_sp, plane_sp,
                   plane_sp, P(ax, None, None), P(ax, None, None)),
@@ -1148,7 +1148,7 @@ def sharded_seed_scatter(x: jax.Array, at_src: jax.Array, at_dst: jax.Array,
         new = x.at[ldst].max(rows.astype(x.dtype), mode="drop")
         return new, jnp.any(new != x, axis=-1)
 
-    sm = shard_map(shard_body, mesh=mesh, check_rep=False,
+    sm = shard_map(shard_body, mesh=mesh, check_vma=False,
                    in_specs=(plane_sp, rep, rep),
                    out_specs=(plane_sp, vec_sp))
     return sm(x, jnp.asarray(at_src, jnp.int32),
@@ -1180,7 +1180,7 @@ def sharded_seed_scatter_min(x: jax.Array, at_src: jax.Array,
         new = x.at[ldst].min(rows, mode="drop")
         return new, jnp.any(new != x, axis=-1)
 
-    sm = shard_map(shard_body, mesh=mesh, check_rep=False,
+    sm = shard_map(shard_body, mesh=mesh, check_vma=False,
                    in_specs=(plane_sp, rep, rep),
                    out_specs=(plane_sp, vec_sp))
     return sm(x, jnp.asarray(at_src, jnp.int32),
@@ -1217,7 +1217,7 @@ def sharded_il_rows(il, u: jax.Array, v: jax.Array, *, mesh: Mesh):
         w = il_in.shape[1]
         return tuple(cat[:, i * w:(i + 1) * w] for i in range(4))
 
-    sm = shard_map(shard_body, mesh=mesh, check_rep=False,
+    sm = shard_map(shard_body, mesh=mesh, check_vma=False,
                    in_specs=(plane_sp, plane_sp, rep, rep),
                    out_specs=(rep,) * 4)
     return sm(il_in, il_out, jnp.asarray(u, jnp.int32),
@@ -1257,7 +1257,7 @@ def sharded_rows(p: Q.PackedLabels, u: jax.Array, v: jax.Array, *,
             off += w
         return tuple(outs)
 
-    sm = shard_map(shard_body, mesh=mesh, check_rep=False,
+    sm = shard_map(shard_body, mesh=mesh, check_vma=False,
                    in_specs=(plane_sp,) * 4 + (rep, rep),
                    out_specs=(rep,) * 8)
     return Q.RowBlocks(*sm(p.dl_in, p.dl_out, p.bl_in, p.bl_out,
@@ -1326,7 +1326,7 @@ def _sharded_bfs_impl(p, dlo_u, blin_v, blout_v, u, v, live, m_cut, m_total,
         return hit
 
     sm = shard_map(
-        shard_body, mesh=mesh, check_rep=False,
+        shard_body, mesh=mesh, check_vma=False,
         in_specs=(plane_sp, plane_sp, plane_sp, rep, rep, rep, rep, rep,
                   rep, rep, rep, rep,
                   plane_sp, plane_sp, plane_sp, plane_sp,

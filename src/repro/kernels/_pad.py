@@ -1,8 +1,9 @@
-"""Shared padding helper for the kernel ops wrappers.
+"""Helpers shared by both Pallas kernel packages.
 
-Both Pallas kernel packages pad their word-major streams (and per-lane
-cutoff rows) up to block multiples before the ``pallas_call``; keeping one
-implementation stops the two wrappers' padding semantics from drifting.
+Both pad their word-major streams (and per-lane cutoff rows) up to block
+multiples before the ``pallas_call`` and take the same optional cutoff
+operand pairs; keeping one implementation of each stops the two packages'
+semantics from drifting.
 """
 from __future__ import annotations
 
@@ -18,3 +19,14 @@ def pad_axis(x, mult: int, axis: int, value=0):
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, rem)
     return jnp.pad(x, pad, constant_values=value)
+
+
+def check_cut_args(m_cut, m_total, d_cut, d_total):
+    """The cutoff operands come in (cut, total) pairs, and the tombstone
+    pair needs the edge-count pair."""
+    assert (m_cut is None) == (m_total is None), \
+        "pass m_cut and m_total together"
+    assert (d_cut is None) == (d_total is None), \
+        "pass d_cut and d_total together"
+    assert d_cut is None or m_cut is not None, \
+        "the tombstone cutoff requires the edge-count cutoff operands"

@@ -27,6 +27,15 @@ reachability, so the DL-intersection evidence can be stale and the ``d``
 term drops for deletion-stale lanes too.  The BL containment prunes remain
 sound under tombstones — bits are never removed, and the edge-wise label
 coherence invariant holds along every live path — so they stay on.
+
+Layout on the chip: a tile is (NB, QB) with queries on lanes, so every
+vertex-side word must become a column and every query-side word a row.  The
+kernel loads word-major (W, NB) vertex blocks (lane-dense DMA), transposes
+them in VMEM and takes (NB, 1) lane slices; query rows are (1, QB) ref
+slices.  The cutoffs are pre-combined in XLA into one (1, Q) uint32 mask row
+(all ones = fresh, zero = DL prune off), so the whole prune is uint32 word
+algebra with a single compare at the end — the TPU lowers no select between
+bool operands of a 32-bit layout into an int8 tile.
 """
 from __future__ import annotations
 
@@ -37,39 +46,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._pad import check_cut_args
 
-def _make_kernel(wd: int, wb: int, with_cut: bool, with_del: bool):
+
+def _fresh_mask(m_cut, m_total, d_cut, d_total, q: int):
+    """(1, Q) uint32 DL-prune gate: all ones on fresh lanes, 0 on stale
+    ones (m- and d-cutoff both gate the same DL term, so one row suffices).
+    None when no cutoffs are given."""
+    if m_cut is None:
+        return None
+    fresh = (jnp.reshape(m_cut, (1, q)).astype(jnp.int32)
+             >= jnp.reshape(m_total, ()).astype(jnp.int32))
+    if d_cut is not None:
+        fresh &= (jnp.reshape(d_cut, (1, q)).astype(jnp.int32)
+                  >= jnp.reshape(d_total, ()).astype(jnp.int32))
+    return jnp.where(fresh, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+
+
+def _admit_tile(bia, boa, dia, row, fresh):
+    """One (NB, QB) int8 admit tile.
+
+    ``bia``/``boa`` (Wb, NB) and ``dia`` (Wd, NB) are word-major vertex
+    values; ``row(name, w)`` loads query word ``w`` of ``"biv"``, ``"bov"``
+    or ``"dou"`` as a (1, QB) row; ``fresh`` is the (1, QB) uint32 gate or
+    None.  Shared verbatim by the grid and the streamed kernel."""
+    wb, wd = bia.shape[0], dia.shape[0]
+    bia, boa, dia = bia.T, boa.T, dia.T          # (NB, W): words on lanes
+    # any set bit in ``bad`` rejects x for lane q: a BL containment
+    # violation (c1/c2) or a DL intersection (the d term)
+    bad = None
+    for w in range(wb):  # static unroll: W is k'/32 (tiny)
+        t = ((bia[:, w:w + 1] & ~row("biv", w))
+             | (row("bov", w) & ~boa[:, w:w + 1]))
+        bad = t if bad is None else bad | t
+    for w in range(wd):
+        t = row("dou", w) & dia[:, w:w + 1]
+        if fresh is not None:
+            t = t & fresh
+        bad = bad | t
+    return (bad == jnp.uint32(0)).astype(jnp.int8)
+
+
+def _make_kernel(with_cut: bool):
     def kernel(blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u,
                *rest):
-        if with_del:
-            m_cut, m_total, d_cut, d_total, out = rest
-        elif with_cut:
-            m_cut, m_total, out = rest
-        else:
-            (out,) = rest
-        z = jnp.uint32(0)
-        bia, boa, dia = blin_all[...], blout_all[...], dlin_all[...]
-        biv, bov, dou = blin_v[...], blout_v[...], dlo_u[...]
-        nb = bia.shape[1]
-        qb = biv.shape[1]
-        c1 = jnp.ones((nb, qb), jnp.bool_)
-        c2 = jnp.ones((nb, qb), jnp.bool_)
-        for w in range(wb):  # static unroll: W is k'/32 (tiny)
-            c1 &= (bia[w, :, None] & ~biv[w, None, :]) == z
-            c2 &= (bov[w, None, :] & ~boa[w, :, None]) == z
-        d = jnp.zeros((nb, qb), jnp.bool_)
-        for w in range(wd):
-            d |= (dou[w, None, :] & dia[w, :, None]) != z
-        if with_cut:
-            fresh = m_cut[...][0, :] >= m_total[...][0, 0]   # (QB,)
-            if with_del:
-                # tombstone operand: a lane answered from deletion-stale
-                # labels (d_cut < d_total) loses the DL prune too — its
-                # soundness rests on positive DL evidence, which may
-                # certify paths that tombstoned edges no longer carry
-                fresh &= d_cut[...][0, :] >= d_total[...][0, 0]
-            d &= fresh[None, :]
-        out[...] = (c1 & c2 & ~d).astype(jnp.int8)
+        out = rest[-1]
+        q_refs = {"biv": blin_v, "bov": blout_v, "dou": dlo_u}
+        out[...] = _admit_tile(
+            blin_all[...], blout_all[...], dlin_all[...],
+            lambda name, w: q_refs[name][pl.ds(w, 1), :],
+            rest[0][...] if with_cut else None)
     return kernel
 
 
@@ -77,8 +103,11 @@ def _make_kernel(wd: int, wb: int, with_cut: bool, with_del: bool):
 def bfs_admit_plane(blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u,
                     m_cut=None, m_total=None, d_cut=None, d_total=None,
                     *, n_block: int = 1024, q_block: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """word-major inputs: *_all (W, n); per-query (W, Q). -> (n, Q) int8.
+
+    ``interpret`` is required: True runs the Pallas interpreter (CPU
+    tests), False compiles the kernel for the TPU.
 
     Optional ``m_cut`` (1, Q) int32 per-lane edge-count cutoff and
     ``m_total`` (1, 1) int32 newest edge count: stale lanes
@@ -95,11 +124,7 @@ def bfs_admit_plane(blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u,
     wd = dlin_all.shape[0]
     q = blin_v.shape[1]
     assert n % n_block == 0 and q % q_block == 0, (n, n_block, q, q_block)
-    assert (m_cut is None) == (m_total is None), "pass m_cut and m_total together"
-    assert (d_cut is None) == (d_total is None), "pass d_cut and d_total together"
-    assert d_cut is None or m_cut is not None, \
-        "the tombstone cutoff requires the edge-count cutoff operands"
-    grid = (n // n_block, q // q_block)
+    check_cut_args(m_cut, m_total, d_cut, d_total)
 
     in_specs = [
         pl.BlockSpec((wb, n_block), lambda i, j: (0, i)),
@@ -110,20 +135,14 @@ def bfs_admit_plane(blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u,
         pl.BlockSpec((wd, q_block), lambda i, j: (0, j)),
     ]
     args = [blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u]
-    with_cut = m_cut is not None
-    with_del = d_cut is not None
-    if with_cut:
-        in_specs += [pl.BlockSpec((1, q_block), lambda i, j: (0, j)),
-                     pl.BlockSpec((1, 1), lambda i, j: (0, 0))]
-        args += [m_cut.astype(jnp.int32), m_total.astype(jnp.int32)]
-    if with_del:
-        in_specs += [pl.BlockSpec((1, q_block), lambda i, j: (0, j)),
-                     pl.BlockSpec((1, 1), lambda i, j: (0, 0))]
-        args += [d_cut.astype(jnp.int32), d_total.astype(jnp.int32)]
+    fresh = _fresh_mask(m_cut, m_total, d_cut, d_total, q)
+    if fresh is not None:
+        in_specs.append(pl.BlockSpec((1, q_block), lambda i, j: (0, j)))
+        args.append(fresh)
 
     return pl.pallas_call(
-        _make_kernel(wd, wb, with_cut, with_del),
-        grid=grid,
+        _make_kernel(fresh is not None),
+        grid=(n // n_block, q // q_block),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((n_block, q_block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, q), jnp.int8),
@@ -132,30 +151,25 @@ def bfs_admit_plane(blin_all, blout_all, dlin_all, blin_v, blout_v, dlo_u,
 
 
 # ------------------------------------------------- streamed (double-buffered)
-def _make_streamed_kernel(wd: int, wb: int, with_cut: bool):
+def _make_streamed_kernel(with_cut: bool):
     """Single-program admit-plane kernel streaming the VERTEX axis: the
     query-side operands (a few (W, Q) blocks) are DMA'd into VMEM once,
     then the big word-major vertex planes ride a two-slot HBM→VMEM pipeline
     — chunk ``i+1``'s copy overlaps chunk ``i``'s (NB, Q) tile compute, and
     each tile's DMA back to HBM overlaps the next compute.  The prune
-    algebra is ``_make_kernel``'s, verbatim; the cutoff comparisons are
-    pre-combined host-side into one 0/1 freshness lane (``m`` and ``d``
-    cutoffs both gate the same DL term, so one row suffices)."""
+    algebra is ``_admit_tile``, shared with the grid kernel."""
     def kernel(bl_h, dl_h, qbl_h, qdl_h, *rest):
-        if with_cut:
-            fr_h, out_h = rest
-        else:
-            (out_h,) = rest
-        nchunks, _, _, nb = bl_h.shape
+        out_h = rest[-1]
+        nchunks, _, wb, nb = bl_h.shape
+        wd = dl_h.shape[1]
         qb = qbl_h.shape[2]
-        n_q = 2 + (1 if with_cut else 0)
+        q_srcs = [qbl_h, qdl_h] + ([rest[0]] if with_cut else [])
 
         def body(bl_s, dl_s, qbl_s, qdl_s, fr_s, o_s, in_sem, q_sem,
                  out_sem):
-            qcps = [pltpu.make_async_copy(qbl_h, qbl_s, q_sem.at[0]),
-                    pltpu.make_async_copy(qdl_h, qdl_s, q_sem.at[1])]
-            if with_cut:
-                qcps.append(pltpu.make_async_copy(fr_h, fr_s, q_sem.at[2]))
+            q_dsts = [qbl_s, qdl_s, fr_s]
+            qcps = [pltpu.make_async_copy(src, dst, q_sem.at[j])
+                    for j, (src, dst) in enumerate(zip(q_srcs, q_dsts))]
             for c in qcps:
                 c.start()
             for c in qcps:
@@ -170,6 +184,11 @@ def _make_streamed_kernel(wd: int, wb: int, with_cut: bool):
             for c in copies(0, 0):
                 c.start()
 
+            def row(name, w):
+                if name == "dou":
+                    return qdl_s[pl.ds(w, 1), :]
+                return qbl_s[0 if name == "biv" else 1, pl.ds(w, 1), :]
+
             def step(ci, carry):
                 slot = jax.lax.rem(ci, 2)
 
@@ -180,28 +199,15 @@ def _make_streamed_kernel(wd: int, wb: int, with_cut: bool):
 
                 for c in copies(ci, slot):
                     c.wait()
-                blk = bl_s[slot]              # (2, wb, nb)
-                bia, boa = blk[0], blk[1]
-                dia = dl_s[slot]              # (wd, nb)
-                biv, bov = qbl_s[0], qbl_s[1]
-                dou = qdl_s[...]
-                z = jnp.uint32(0)
-                c1 = jnp.ones((nb, qb), jnp.bool_)
-                c2 = jnp.ones((nb, qb), jnp.bool_)
-                for w in range(wb):
-                    c1 &= (bia[w, :, None] & ~biv[w, None, :]) == z
-                    c2 &= (bov[w, None, :] & ~boa[w, :, None]) == z
-                d = jnp.zeros((nb, qb), jnp.bool_)
-                for w in range(wd):
-                    d |= (dou[w, None, :] & dia[w, :, None]) != z
-                if with_cut:
-                    d &= (fr_s[0] != 0)[None, :]
+                tile = _admit_tile(bl_s[slot, 0], bl_s[slot, 1],
+                                   dl_s[slot], row,
+                                   fr_s[...] if with_cut else None)
 
                 @pl.when(ci >= 2)
                 def _():
                     pltpu.make_async_copy(o_s.at[slot], out_h.at[ci - 2],
                                           out_sem.at[slot]).wait()
-                o_s[slot] = (c1 & c2 & ~d).astype(jnp.int8)
+                o_s[slot] = tile
                 pltpu.make_async_copy(o_s.at[slot], out_h.at[ci],
                                       out_sem.at[slot]).start()
                 return carry
@@ -216,10 +222,10 @@ def _make_streamed_kernel(wd: int, wb: int, with_cut: bool):
                       pltpu.VMEM((2, wd, nb), jnp.uint32),
                       pltpu.VMEM((2, wb, qb), jnp.uint32),
                       pltpu.VMEM((wd, qb), jnp.uint32),
-                      pltpu.VMEM((1, qb), jnp.int32),
+                      pltpu.VMEM((1, qb), jnp.uint32),
                       pltpu.VMEM((2, nb, qb), jnp.int8),
                       pltpu.SemaphoreType.DMA((2, 2)),
-                      pltpu.SemaphoreType.DMA((n_q,)),
+                      pltpu.SemaphoreType.DMA((len(q_srcs),)),
                       pltpu.SemaphoreType.DMA((2,)))
     return kernel
 
@@ -230,7 +236,7 @@ def bfs_admit_plane_streamed(blin_all, blout_all, dlin_all,
                              m_cut=None, m_total=None,
                              d_cut=None, d_total=None,
                              *, n_block: int = 1024,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool) -> jax.Array:
     """Double-buffered variant of ``bfs_admit_plane`` — same contract,
     bitwise-identical (n, Q) int8 plane.  The vertex axis is chunked into
     ``n_block`` rows and streamed while the query-side operands stay
@@ -240,28 +246,19 @@ def bfs_admit_plane_streamed(blin_all, blout_all, dlin_all,
     wd = dlin_all.shape[0]
     q = blin_v.shape[1]
     assert n % n_block == 0, (n, n_block)
-    assert (m_cut is None) == (m_total is None), "pass m_cut and m_total together"
-    assert (d_cut is None) == (d_total is None), "pass d_cut and d_total together"
-    assert d_cut is None or m_cut is not None, \
-        "the tombstone cutoff requires the edge-count cutoff operands"
+    check_cut_args(m_cut, m_total, d_cut, d_total)
     nchunks = n // n_block
     bl = jnp.stack([blin_all, blout_all])
     bl = bl.reshape(2, wb, nchunks, n_block).transpose(2, 0, 1, 3)
     dl = dlin_all.reshape(wd, nchunks, n_block).transpose(1, 0, 2)
-    qbl = jnp.stack([blin_v, blout_v])
-    args = [bl, dl, qbl, dlo_u]
-    with_cut = m_cut is not None
-    if with_cut:
-        fresh = (m_cut.astype(jnp.int32)
-                 >= jnp.reshape(m_total, (1, 1)).astype(jnp.int32))
-        if d_cut is not None:
-            fresh &= (d_cut.astype(jnp.int32)
-                      >= jnp.reshape(d_total, (1, 1)).astype(jnp.int32))
-        args.append(fresh.astype(jnp.int32).reshape(1, q))
+    args = [bl, dl, jnp.stack([blin_v, blout_v]), dlo_u]
+    fresh = _fresh_mask(m_cut, m_total, d_cut, d_total, q)
+    if fresh is not None:
+        args.append(fresh)
     out = pl.pallas_call(
-        _make_streamed_kernel(wd, wb, with_cut),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * len(args),
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        _make_streamed_kernel(fresh is not None),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(args),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((nchunks, n_block, q), jnp.int8),
         interpret=interpret,
     )(*args)

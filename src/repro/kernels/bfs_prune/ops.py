@@ -21,7 +21,7 @@ def admit_plane(p: PackedLabels, u: jax.Array, v: jax.Array,
                 d_total: jax.Array | None = None,
                 il=None, il_on: jax.Array | None = None,
                 *, n_block: int = 1024, q_block: int = 128,
-                interpret: bool = True,
+                interpret: bool,
                 out_dtype=jnp.bool_, streaming: bool = False) -> jax.Array:
     """Returns (n_cap, Qc) ``out_dtype`` admit plane for the pruned-BFS
     lanes (``jnp.int8`` hands the kernel's narrow plane through without a

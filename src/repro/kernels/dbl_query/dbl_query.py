@@ -8,9 +8,16 @@ exactly once, (b) fusing all four rules so no (Q, W) intermediates ever hit
 HBM, and (c) a word-major (W, Q) layout that puts queries on the 128-wide VPU
 lanes and words on sublanes (the reduction axis).
 
-Block shape: (W, QB) per stream with QB a multiple of 128; W is tiny (k/32,
-e.g. 2 for k=64) so a block is a few KB and many grid steps stay resident in
-VMEM while the DMA pipeline streams the next blocks.
+Block shape: (W, QB) per stream with QB a multiple of 128 (or the whole query
+axis); W is tiny (k/32, e.g. 2 for k=64) so a block is a few KB and many grid
+steps stay resident in VMEM while the DMA pipeline streams the next blocks.
+
+Per-lane operands travel as 2-D ``(1, Q)`` rows, never 1-D vectors: the TPU
+compiler tiles a 1-D int32 array differently from a Mosaic block of it.  They
+ride in one ``(R, 1, Q)`` int32 *flag stack* — row 0 is the self-query flag,
+rows 1 and 2 (when present) the 0/1 freshness of the two staleness cutoffs —
+so the kernel indexes rows along an untiled leading axis and reads no
+scalars.  The cutoff comparisons happen in XLA in front of the call.
 
 Fully-dynamic serving adds a second per-lane cutoff operand pair alongside
 the edge-count cutoff: ``d_cut`` (Q,) int32 against ``d_total`` (1,) int32
@@ -31,62 +38,87 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._pad import check_cut_args
 
-def _make_kernel(with_cut: bool, with_del: bool, with_il: bool = False):
+
+def _flag_stack(same, m_cut, m_total, d_cut, d_total):
+    """(R, 1, Q) int32 per-lane flags: [u == v, m-fresh?, d-fresh?] as 0/1."""
+    rows = [same.astype(jnp.int32)]
+    for cut, total in ((m_cut, m_total), (d_cut, d_total)):
+        if cut is not None:
+            tot = jnp.reshape(total, ()).astype(jnp.int32)
+            rows.append((cut.astype(jnp.int32) >= tot).astype(jnp.int32))
+    q = same.shape[0]
+    return jnp.stack([jnp.reshape(r, (1, q)) for r in rows])
+
+
+def _verdict(dl, bl, flags, il=None):
+    """The fused Alg-2 verdict of one (·, QB) tile -> (1, QB) int32.
+
+    ``dl`` = (dlo_u, dli_v, dlo_v, dli_u) and ``bl`` = (blin_u, blin_v,
+    blout_u, blout_v) are (W, QB) uint32 word-major values; ``flags`` the
+    (1, QB) int32 rows of the flag stack; ``il`` four (2*dim, QB) int32 rank
+    rows or None.  Shared verbatim by the grid and the streamed kernel."""
+    dlo_u, dli_v, dlo_v, dli_u = dl
+    blin_u, blin_v, blout_u, blout_v = bl
+    z = jnp.uint32(0)
+
+    def any_word(x):
+        return jnp.any(x != z, axis=0, keepdims=True)
+
+    is_same = flags[0] != 0
+    pos_lbl = any_word(dlo_u & dli_v)
+    pos = pos_lbl | is_same
+    bl_neg = any_word(blin_u & ~blin_v) | any_word(blout_v & ~blout_u)
+    thm = (any_word(dlo_v & dli_u)
+           | any_word(dlo_u & dli_u) | any_word(dlo_v & dli_v))
+    neg_lbl = bl_neg
+    if il is not None:
+        # interval containment violation (plug-in negative prune): a pure
+        # elementwise greater-than sweep over the rank sublanes.  Insert-
+        # monotone like BL, so it skips the m-cut; it joins ONLY the
+        # d-fresh branch below (contributes nothing while dirty).  Padding
+        # lanes carry rank 0 on both sides: 0 > 0 never prunes.
+        ilo_u, ilo_v, ili_u, ili_v = il
+        neg_lbl = (neg_lbl | jnp.any(ilo_u > ilo_v, axis=0, keepdims=True)
+                   | jnp.any(ili_v > ili_u, axis=0, keepdims=True))
+    neg = ~pos & (neg_lbl | thm)
+    if len(flags) > 1:
+        # per-lane edge-count cutoff: a positive proven only by labels
+        # NEWER than the lane's snapshot (stale lane) may ride edges the
+        # snapshot did not have — downgrade it to unknown; negatives and
+        # self-queries are monotone-safe and survive any cutoff.
+        fresh = flags[1] != 0
+        if len(flags) > 2:
+            # tombstone cutoff: lanes whose labels carry un-rebuilt
+            # DELETIONS lose every verdict that rests on positive label
+            # evidence — DL positives AND the theorem-1/2 negatives — since
+            # stale bits may certify paths that no longer exist.  Only
+            # self-queries and BL-containment negatives (which need
+            # completeness, not exactness, and bits are never removed)
+            # survive.  Written as mask algebra: a select between two bool
+            # operands does not lower on the TPU.
+            d_fresh = flags[2] != 0
+            fresh = fresh & d_fresh
+            neg = (d_fresh & neg) | (~d_fresh & ~is_same & bl_neg)
+        pos = (pos_lbl & fresh) | is_same
+    return jnp.where(pos, jnp.int32(1),
+                     jnp.where(neg, jnp.int32(0), jnp.int32(-1)))
+
+
+def _make_kernel(nflags: int, with_il: bool):
     def kernel(dlo_u, dli_v, dlo_v, dli_u,
-               blin_u, blin_v, blout_u, blout_v, same, *rest):
-        rest = list(rest)
+               blin_u, blin_v, blout_u, blout_v, flags, *rest):
+        il = None
         if with_il:
             # four (2*dim, QB) int32 interval-rank streams, word-major like
             # the label words: queries on lanes, interval ends on sublanes
-            ilo_u, ilo_v, ili_u, ili_v = rest[:4]
-            rest = rest[4:]
-        if with_del:
-            m_cut, m_total, d_cut, d_total, out = rest
-        elif with_cut:
-            m_cut, m_total, out = rest
-        else:
-            (out,) = rest
-        z = jnp.uint32(0)
-        pos_lbl = jnp.any((dlo_u[...] & dli_v[...]) != z, axis=0)
-        is_same = same[...] != 0
-        pos = pos_lbl | is_same
-        bl_neg = (jnp.any((blin_u[...] & ~blin_v[...]) != z, axis=0)
-                  | jnp.any((blout_v[...] & ~blout_u[...]) != z, axis=0))
-        thm1 = jnp.any((dlo_v[...] & dli_u[...]) != z, axis=0)
-        thm2 = (jnp.any((dlo_u[...] & dli_u[...]) != z, axis=0)
-                | jnp.any((dlo_v[...] & dli_v[...]) != z, axis=0))
-        neg_lbl = bl_neg
-        if with_il:
-            # interval containment violation (plug-in negative prune):
-            # pure elementwise greater-than sweep over the rank sublanes.
-            # Insert-monotone like BL, so it skips the m-cut; it joins ONLY
-            # the d-fresh branch below (contributes nothing while dirty).
-            # Padding lanes carry rank 0 on both sides: 0 > 0 never prunes.
-            neg_lbl = neg_lbl | jnp.any(ilo_u[...] > ilo_v[...], axis=0) \
-                | jnp.any(ili_v[...] > ili_u[...], axis=0)
-        neg = ~pos & (neg_lbl | thm1 | thm2)
-        if with_cut:
-            # per-lane edge-count cutoff: a positive proven only by labels
-            # NEWER than the lane's snapshot (stale lane) may ride edges the
-            # snapshot did not have — downgrade it to unknown; negatives and
-            # self-queries are monotone-safe and survive any cutoff.
-            fresh = m_cut[...] >= m_total[...][0]
-            if with_del:
-                # tombstone cutoff: lanes whose labels carry un-rebuilt
-                # DELETIONS (d_cut < d_total) lose every verdict that rests
-                # on positive label evidence — DL positives AND the
-                # theorem-1/2 negatives — since stale bits may certify
-                # paths that no longer exist.  Only self-queries and
-                # BL-containment negatives (which need completeness, not
-                # exactness, and bits are never removed) survive.
-                d_fresh = d_cut[...] >= d_total[...][0]
-                pos = (pos_lbl & fresh & d_fresh) | is_same
-                neg = jnp.where(d_fresh, neg, ~is_same & bl_neg)
-            else:
-                pos = (pos_lbl & fresh) | is_same
-        out[...] = jnp.where(pos, jnp.int32(1),
-                             jnp.where(neg, jnp.int32(0), jnp.int32(-1)))
+            il = tuple(r[...] for r in rest[:4])
+        out = rest[-1]
+        out[...] = _verdict(
+            (dlo_u[...], dli_v[...], dlo_v[...], dli_u[...]),
+            (blin_u[...], blin_v[...], blout_u[...], blout_v[...]),
+            [flags[r] for r in range(nflags)], il)
     return kernel
 
 
@@ -95,10 +127,12 @@ def dbl_query_verdicts(dlo_u, dli_v, dlo_v, dli_u,
                        blin_u, blin_v, blout_u, blout_v, same,
                        m_cut=None, m_total=None, d_cut=None, d_total=None,
                        il_rows=None,
-                       *, q_block: int = 512, interpret: bool = True):
+                       *, q_block: int = 512, interpret: bool):
     """All label args (W, Q) uint32 word-major; same (Q,) int32. -> (Q,) int32.
 
     Q must be a multiple of q_block (callers pad; see ops.py).
+    ``interpret`` is required: True runs the Pallas interpreter (CPU
+    tests), False compiles the kernel for the TPU.
 
     Optional ``il_rows`` = (ilo_u, ilo_v, ili_u, ili_v), four (2*dim, Q)
     int32 word-major interval-rank streams of the "il" plug-in family:
@@ -125,11 +159,9 @@ def dbl_query_verdicts(dlo_u, dli_v, dlo_v, dli_u,
     wb = blin_u.shape[0]
     q = dlo_u.shape[1]
     assert q % q_block == 0, (q, q_block)
-    assert (m_cut is None) == (m_total is None), "pass m_cut and m_total together"
-    assert (d_cut is None) == (d_total is None), "pass d_cut and d_total together"
-    assert d_cut is None or m_cut is not None, \
-        "the tombstone cutoff requires the edge-count cutoff operands"
-    grid = (q // q_block,)
+    check_cut_args(m_cut, m_total, d_cut, d_total)
+    flags = _flag_stack(same, m_cut, m_total, d_cut, d_total)
+    nflags = flags.shape[0]
 
     def dl_spec():
         return pl.BlockSpec((wd, q_block), lambda i: (0, i))
@@ -139,71 +171,48 @@ def dbl_query_verdicts(dlo_u, dli_v, dlo_v, dli_u,
 
     in_specs = [dl_spec(), dl_spec(), dl_spec(), dl_spec(),
                 bl_spec(), bl_spec(), bl_spec(), bl_spec(),
-                pl.BlockSpec((q_block,), lambda i: (i,))]
+                pl.BlockSpec((nflags, 1, q_block), lambda i: (0, 0, i))]
     args = [dlo_u, dli_v, dlo_v, dli_u,
-            blin_u, blin_v, blout_u, blout_v, same]
-    with_cut = m_cut is not None
-    with_del = d_cut is not None
+            blin_u, blin_v, blout_u, blout_v, flags]
     with_il = il_rows is not None
     if with_il:
         wi = il_rows[0].shape[0]
         in_specs += [pl.BlockSpec((wi, q_block), lambda i: (0, i))] * 4
         args += [r.astype(jnp.int32) for r in il_rows]
-    if with_cut:
-        in_specs += [pl.BlockSpec((q_block,), lambda i: (i,)),
-                     pl.BlockSpec((1,), lambda i: (0,))]
-        args += [m_cut.astype(jnp.int32),
-                 jnp.reshape(m_total, (1,)).astype(jnp.int32)]
-    if with_del:
-        in_specs += [pl.BlockSpec((q_block,), lambda i: (i,)),
-                     pl.BlockSpec((1,), lambda i: (0,))]
-        args += [d_cut.astype(jnp.int32),
-                 jnp.reshape(d_total, (1,)).astype(jnp.int32)]
 
-    return pl.pallas_call(
-        _make_kernel(with_cut, with_del, with_il),
-        grid=grid,
+    out = pl.pallas_call(
+        _make_kernel(nflags, with_il),
+        grid=(q // q_block,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((q_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
+        out_specs=pl.BlockSpec((1, q_block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, q), jnp.int32),
         interpret=interpret,
     )(*args)
+    return out.reshape(q)
 
 
 # ------------------------------------------------- streamed (double-buffered)
-def _make_streamed_kernel(ncut: int):
-    """Single-program kernel: all operands live in HBM (``pltpu.ANY``) and
+def _make_streamed_kernel(nflags: int):
+    """Single-program kernel: all operands live in HBM (``pl.ANY``) and
     are streamed through a two-slot VMEM scratch by explicit async copies —
     while chunk ``i`` computes, chunk ``i+1``'s HBM→VMEM DMA is in flight,
     and chunk ``i``'s verdict DMA back to HBM overlaps the next compute
     (its semaphore is only awaited when the slot comes around again).
 
-    ``ncut`` is the number of pre-combined freshness rows riding along
-    (0 = no cutoffs, 1 = edge-count, 2 = edge-count + tombstone); the
-    comparisons against ``m_total``/``d_total`` happen host-side so the
-    kernel sees plain 0/1 lanes — the verdict algebra itself is copied
-    verbatim from ``_make_kernel`` for bitwise parity."""
-    def kernel(dl_h, bl_h, sm_h, *rest):
-        if ncut:
-            cut_h, out_h = rest
-        else:
-            (out_h,) = rest
+    Every chunk operand carries its chunk index on a leading untiled axis —
+    (4, W, QB) label stacks, the (R, 1, QB) flag stack, a (1, QB) verdict
+    row — so slot and row selection never slices a tiled dimension.  The
+    verdict algebra is ``_verdict``, shared with the grid kernel."""
+    def kernel(dl_h, bl_h, fl_h, out_h):
         nchunks, _, wd, qb = dl_h.shape
         wb = bl_h.shape[2]
-        n_in = 3 + (1 if ncut else 0)
 
-        def body(dl_s, bl_s, sm_s, ct_s, o_s, in_sem, out_sem):
+        def body(dl_s, bl_s, fl_s, o_s, in_sem, out_sem):
             def copies(ci, slot):
-                cps = [pltpu.make_async_copy(dl_h.at[ci], dl_s.at[slot],
-                                             in_sem.at[slot, 0]),
-                       pltpu.make_async_copy(bl_h.at[ci], bl_s.at[slot],
-                                             in_sem.at[slot, 1]),
-                       pltpu.make_async_copy(sm_h.at[ci], sm_s.at[slot],
-                                             in_sem.at[slot, 2])]
-                if ncut:
-                    cps.append(pltpu.make_async_copy(
-                        cut_h.at[ci], ct_s.at[slot], in_sem.at[slot, 3]))
-                return cps
+                return [pltpu.make_async_copy(src.at[ci], dst.at[slot],
+                                              in_sem.at[j, slot])
+                        for j, (src, dst) in enumerate(
+                            ((dl_h, dl_s), (bl_h, bl_s), (fl_h, fl_s)))]
 
             for c in copies(0, 0):
                 c.start()
@@ -218,26 +227,11 @@ def _make_streamed_kernel(ncut: int):
 
                 for c in copies(ci, slot):
                     c.wait()
-                dl = dl_s[slot]          # (4, wd, qb): dlo_u dli_v dlo_v dli_u
-                bl = bl_s[slot]          # (4, wb, qb): bi_u bi_v bo_u bo_v
-                z = jnp.uint32(0)
-                pos_lbl = jnp.any((dl[0] & dl[1]) != z, axis=0)
-                is_same = sm_s[slot] != 0
-                pos = pos_lbl | is_same
-                bl_neg = (jnp.any((bl[0] & ~bl[1]) != z, axis=0)
-                          | jnp.any((bl[3] & ~bl[2]) != z, axis=0))
-                thm1 = jnp.any((dl[2] & dl[3]) != z, axis=0)
-                thm2 = (jnp.any((dl[0] & dl[3]) != z, axis=0)
-                        | jnp.any((dl[2] & dl[1]) != z, axis=0))
-                neg = ~pos & (bl_neg | thm1 | thm2)
-                if ncut:
-                    fresh = ct_s[slot][0] != 0
-                    if ncut == 2:
-                        d_fresh = ct_s[slot][1] != 0
-                        pos = (pos_lbl & fresh & d_fresh) | is_same
-                        neg = jnp.where(d_fresh, neg, ~is_same & bl_neg)
-                    else:
-                        pos = (pos_lbl & fresh) | is_same
+                # dl: dlo_u dli_v dlo_v dli_u   bl: bi_u bi_v bo_u bo_v
+                verd = _verdict(
+                    tuple(dl_s[slot, j] for j in range(4)),
+                    tuple(bl_s[slot, j] for j in range(4)),
+                    [fl_s[slot, r] for r in range(nflags)])
 
                 # the slot's previous verdict DMA (chunk ci-2) must have
                 # landed before its buffer is overwritten
@@ -245,9 +239,7 @@ def _make_streamed_kernel(ncut: int):
                 def _():
                     pltpu.make_async_copy(o_s.at[slot], out_h.at[ci - 2],
                                           out_sem.at[slot]).wait()
-                o_s[slot] = jnp.where(pos, jnp.int32(1),
-                                      jnp.where(neg, jnp.int32(0),
-                                                jnp.int32(-1)))
+                o_s[slot] = verd
                 pltpu.make_async_copy(o_s.at[slot], out_h.at[ci],
                                       out_sem.at[slot]).start()
                 return carry
@@ -260,10 +252,9 @@ def _make_streamed_kernel(ncut: int):
         pl.run_scoped(body,
                       pltpu.VMEM((2, 4, wd, qb), jnp.uint32),
                       pltpu.VMEM((2, 4, wb, qb), jnp.uint32),
-                      pltpu.VMEM((2, qb), jnp.int32),
-                      pltpu.VMEM((2, max(ncut, 1), qb), jnp.int32),
-                      pltpu.VMEM((2, qb), jnp.int32),
-                      pltpu.SemaphoreType.DMA((2, n_in)),
+                      pltpu.VMEM((2, nflags, 1, qb), jnp.int32),
+                      pltpu.VMEM((2, 1, qb), jnp.int32),
+                      pltpu.SemaphoreType.DMA((3, 2)),
                       pltpu.SemaphoreType.DMA((2,)))
     return kernel
 
@@ -274,44 +265,30 @@ def dbl_query_verdicts_streamed(dlo_u, dli_v, dlo_v, dli_u,
                                 m_cut=None, m_total=None,
                                 d_cut=None, d_total=None,
                                 *, q_block: int = 512,
-                                interpret: bool = True):
+                                interpret: bool):
     """Double-buffered variant of ``dbl_query_verdicts`` — same contract,
     bitwise-identical output.  The query axis is chunked into ``q_block``
     columns and the (4, W, QB) label stacks are streamed HBM→VMEM with the
     next chunk's copy overlapping the current chunk's verdict compute (the
-    grid-free ``pltpu.ANY`` + ``make_async_copy`` pipeline).  The cutoff
-    comparisons are hoisted to XLA: the kernel receives pre-combined 0/1
-    freshness lanes instead of (cut, total) pairs."""
+    grid-free ``pl.ANY`` + ``make_async_copy`` pipeline)."""
     wd = dlo_u.shape[0]
     wb = blin_u.shape[0]
     q = dlo_u.shape[1]
     assert q % q_block == 0, (q, q_block)
-    assert (m_cut is None) == (m_total is None), "pass m_cut and m_total together"
-    assert (d_cut is None) == (d_total is None), "pass d_cut and d_total together"
-    assert d_cut is None or m_cut is not None, \
-        "the tombstone cutoff requires the edge-count cutoff operands"
+    check_cut_args(m_cut, m_total, d_cut, d_total)
     nchunks = q // q_block
     dl = jnp.stack([dlo_u, dli_v, dlo_v, dli_u])
     bl = jnp.stack([blin_u, blin_v, blout_u, blout_v])
     dl = dl.reshape(4, wd, nchunks, q_block).transpose(2, 0, 1, 3)
     bl = bl.reshape(4, wb, nchunks, q_block).transpose(2, 0, 1, 3)
-    sm = same.astype(jnp.int32).reshape(nchunks, q_block)
-    args = [dl, bl, sm]
-    ncut = 0
-    if m_cut is not None:
-        mt = jnp.reshape(m_total, (1,)).astype(jnp.int32)
-        rows = [(m_cut.astype(jnp.int32) >= mt[0]).astype(jnp.int32)]
-        if d_cut is not None:
-            dt = jnp.reshape(d_total, (1,)).astype(jnp.int32)
-            rows.append((d_cut.astype(jnp.int32) >= dt[0]).astype(jnp.int32))
-        ncut = len(rows)
-        cut = jnp.stack(rows).reshape(ncut, nchunks, q_block)
-        args.append(cut.transpose(1, 0, 2))
+    flags = _flag_stack(same, m_cut, m_total, d_cut, d_total)
+    nflags = flags.shape[0]
+    flags = flags.reshape(nflags, 1, nchunks, q_block).transpose(2, 0, 1, 3)
     out = pl.pallas_call(
-        _make_streamed_kernel(ncut),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * len(args),
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((nchunks, q_block), jnp.int32),
+        _make_streamed_kernel(nflags),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((nchunks, 1, q_block), jnp.int32),
         interpret=interpret,
-    )(*args)
+    )(dl, bl, flags)
     return out.reshape(q)

@@ -30,7 +30,7 @@ def verdicts_device(p: PackedLabels, u: jax.Array, v: jax.Array,
                     d_cut: jax.Array | None = None,
                     d_total: jax.Array | None = None,
                     il=None,
-                    *, q_block: int = 512, interpret: bool = True,
+                    *, q_block: int = 512, interpret: bool,
                     out_dtype=jnp.int32, streaming: bool = False
                     ) -> jax.Array:
     """Traceable (un-jitted) body of ``query_verdicts`` so larger programs —
@@ -101,7 +101,7 @@ def verdicts_device(p: PackedLabels, u: jax.Array, v: jax.Array,
 @functools.partial(jax.jit, static_argnames=("q_block", "interpret",
                                              "streaming"))
 def query_verdicts(p: PackedLabels, u: jax.Array, v: jax.Array, il=None,
-                   *, q_block: int = 512, interpret: bool = True,
+                   *, q_block: int = 512, interpret: bool,
                    streaming: bool = False) -> jax.Array:
     """(Q,) int32 verdicts; same contract as core.query.label_verdicts."""
     return verdicts_device(p, u, v, il=il, q_block=q_block,

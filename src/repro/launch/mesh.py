@@ -5,22 +5,18 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes, **kw):
-    """jax.make_mesh across jax versions: ``axis_types`` only exists from
-    jax ≥ 0.5 (and Auto is the default there anyway) — pass it when the
-    installed jax understands it, plain call otherwise."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, **kw)
+def auto_mesh(shape, axes, **kw):
+    """``jax.make_mesh`` with every axis ``Auto``-typed: shardings are
+    propagated by the compiler, as every sharded path here assumes."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_axes(mesh) -> dict:
@@ -31,7 +27,10 @@ def mesh_axes(mesh) -> dict:
             "all": names}
 
 
-# Hardware constants for the roofline (TPU v5e target; see EXPERIMENTS.md)
+# Published peaks of ONE chip, the TPU v5e (Google Cloud documentation,
+# "TPU v5e"), the target the LM/GNN dry-run roofline models from compiled
+# cost analysis (benchmarks/roofline.py, perf_iter.py).  They hold for that
+# chip only; no DBL path reads them.
 PEAK_FLOPS_BF16 = 197e12      # per chip
 HBM_BW = 819e9                # bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per link (assignment-given constant)

@@ -24,7 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs.base import MoEConfig
 
@@ -106,7 +106,7 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, act, *,
         in_specs=(P(all_axes, None), P(), P(tp_axis, None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None)),
         out_specs=(P(all_axes, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, params["router"], params["w1"], params["w3"],
                 params["w2"])
 

@@ -7,9 +7,10 @@ the table: it copies the full verdict vector to the host, slices unknowns
 with numpy, and re-dispatches one padded BFS chunk at a time.  The engine
 keeps the whole pipeline device-resident:
 
-- **backend selected once at construction** — the Pallas ``dbl_query``
-  verdict kernel on TPU, the fused jnp path elsewhere (``"pallas-interpret"``
-  forces the kernel through the Pallas interpreter for parity testing);
+- **backend selected once at construction** — the compiled Pallas
+  ``dbl_query`` verdict kernel on TPU, the fused jnp path elsewhere
+  (``"pallas-interpret"`` runs the kernel in the Pallas interpreter for CPU
+  parity testing; each kernel backend refuses the other kind of host);
   ``streaming=True`` routes kernel backends through the PR-7 double-buffered
   streamed kernels (verdicts + BFS admit planes) instead of the grid forms —
   il-enabled verdict dispatches fall back to the grid kernel with a
@@ -121,11 +122,25 @@ FLUSH_POLICIES = (None, "deadline", "watermark")
 
 
 def select_backend(backend: str = "auto") -> str:
-    """Resolve 'auto' once: the Pallas kernel on TPU, jnp elsewhere."""
+    """Resolve 'auto' once: the compiled Pallas kernels on TPU, jnp
+    elsewhere.  Each kernel backend is tied to its device: ``"pallas"``
+    compiles the kernels for a TPU and ``"pallas-interpret"`` (the CPU test
+    mode) runs them in the Pallas interpreter, so asking for either on the
+    other kind of host is an error, never a silent switch."""
+    on_tpu = jax.default_backend() == "tpu"
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "pallas" if on_tpu else "jnp"
     if backend not in ("jnp", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown backend {backend!r}")
+    if backend == "pallas" and not on_tpu:
+        raise ValueError(
+            "backend='pallas' compiles the Pallas kernels for a TPU, but "
+            f"JAX's default backend is {jax.default_backend()!r}; use "
+            "'pallas-interpret' to run them in the interpreter")
+    if backend == "pallas-interpret" and on_tpu:
+        raise ValueError(
+            "backend='pallas-interpret' runs the kernels in the Pallas "
+            "interpreter, a CPU test mode; on a TPU use 'pallas'")
     return backend
 
 
@@ -335,6 +350,11 @@ class QueryEngine:
             else tuple(int(c) for c in halo_caps)
         self._halo_telemetry = HL.HaloTelemetry()
         self.bfs_kernel = bool(bfs_kernel)
+        if self.bfs_kernel and self.backend == "jnp":
+            raise ValueError(
+                "bfs_kernel=True routes the BFS admit plane through the "
+                "bfs_prune Pallas kernel; construct with backend='pallas' "
+                "(TPU) or 'pallas-interpret' (CPU)")
         self.consistency = select_consistency(consistency)
         self.flush_policy = flush_policy
         self.flush_deadline_ms = float(flush_deadline_ms)
@@ -437,9 +457,7 @@ class QueryEngine:
     def _build_executables(self):
         backend = self.backend
         q_block = self.q_block
-        interpret = (backend == "pallas-interpret"
-                     or jax.default_backend() != "tpu")
-        self._interpret = interpret
+        interpret = backend == "pallas-interpret"
         max_iters = self.max_iters
         use_bfs_kernel = self.bfs_kernel
         streaming = self.streaming

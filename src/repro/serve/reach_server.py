@@ -308,6 +308,8 @@ def main(argv=None):
                          "many devices (0 = replicated)")
     a = ap.parse_args(argv)
 
+    from repro.serve.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core.dbl import DBLIndex
     from repro.core.graph import make_graph
     src, dst = power_law(a.n, a.m, seed=0)

@@ -8,7 +8,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import MoEConfig  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.models.transformer.model import _act  # noqa: E402
 from repro.models.transformer.moe import init_moe_params, moe_ffn  # noqa: E402
 from repro.models.transformer.moe_sharded import moe_ffn_sharded  # noqa: E402
@@ -16,7 +16,7 @@ from repro.models.transformer.moe_sharded import moe_ffn_sharded  # noqa: E402
 
 def main():
     assert len(jax.devices()) == 8
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg = MoEConfig(n_experts=8, top_k=2, d_ff=32,
                     capacity_factor=64.0,  # no-drop regime
                     router_aux_weight=0.0)  # aux estimators differ by a

@@ -14,7 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import DBLIndex, make_graph  # noqa: E402
 from repro.core import distributed as D  # noqa: E402
 from repro.graphs.generators import power_law  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.serve.engine import QueryEngine  # noqa: E402
 
 
@@ -28,7 +28,7 @@ def main():
     # single-device reference
     ref = DBLIndex.build(g, n_cap=n, k=16, k_prime=16, max_iters=64)
 
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     idx = D.distributed_build(g, mesh, n_cap=n, k=16, k_prime=16,
                               max_iters=64)
     for name in ("dl_in", "dl_out", "bl_in", "bl_out"):
@@ -80,7 +80,7 @@ def main():
     assert (ad == br).all(), "rebuild changed dirty-mode answers"
 
     # elastic re-placement: different mesh shape, same results
-    mesh2 = make_mesh_compat((8,), ("data",))
+    mesh2 = auto_mesh((8,), ("data",))
     idx3 = D.shard_index(idx2, mesh2)
     verd3 = np.asarray(D.distributed_label_verdicts(idx3, mesh2, u, v))
     verd2 = np.asarray(ref2.label_verdicts(u, v))

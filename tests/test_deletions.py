@@ -531,8 +531,8 @@ def test_index_scalar_leaves_are_typed_arrays_everywhere():
 
 def test_distributed_epoch_is_int32_array():
     from repro.core import distributed as D
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
     idx, src, dst, rng = _mk(n=16, m=30, mi=20)
     sharded = D.shard_index(idx, mesh)
     assert sharded.epoch.dtype == jnp.int32 and not sharded.epoch.weak_type

@@ -225,6 +225,25 @@ def test_engine_empty_and_errors():
     assert QueryEngine(consistency="latest-snapshot").consistency == "latest"
 
 
+def test_kernel_backends_refuse_the_wrong_host():
+    """'pallas' compiles for a TPU and 'pallas-interpret' is the CPU test
+    mode: asking for either on the other kind of host raises instead of
+    silently running something else."""
+    import jax
+    compiled, interp = ("pallas", "pallas-interpret")
+    if jax.default_backend() == "tpu":
+        compiled, interp = interp, compiled
+    with pytest.raises(ValueError, match="TPU"):
+        select_backend(compiled)
+    with pytest.raises(ValueError, match="TPU"):
+        QueryEngine(backend=compiled)
+    assert QueryEngine(backend=interp).backend == interp
+    # the BFS admit kernel is a Pallas kernel too: no interpreter behind
+    # a jnp backend's back
+    with pytest.raises(ValueError, match="bfs_kernel"):
+        QueryEngine(backend="jnp", bfs_kernel=True)
+
+
 def test_engine_for_is_memoized():
     a = engine_for(bfs_chunk=64, max_iters=33)
     b = engine_for(bfs_chunk=64, max_iters=33)
@@ -497,15 +516,18 @@ def test_aot_cache_key_includes_every_baked_knob(tmp_path):
     e1 = QueryEngine(idx, **base_kw)
     e1.aot_warmup(idx, tmp_path)
     assert e1.aot_cache.stores > 0
-    for flip in (dict(frontier_dtype="int32"),
-                 dict(out_dtype="int32"),
-                 dict(plane_repr="packed"),
-                 dict(bfs_kernel=True),
-                 dict(max_iters=48),
-                 dict(halo_mode="sparse"),
-                 dict(hub_count=8),
-                 dict(halo_caps=(8, 32))):
-        e2 = QueryEngine(idx, **{**base_kw, **flip})
+    # bfs_kernel needs a kernel backend: flip it against a kernel-backed base
+    kernel_kw = dict(base_kw, backend="pallas-interpret")
+    QueryEngine(idx, **kernel_kw).aot_warmup(idx, tmp_path)
+    for base, flip in ((base_kw, dict(frontier_dtype="int32")),
+                       (base_kw, dict(out_dtype="int32")),
+                       (base_kw, dict(plane_repr="packed")),
+                       (kernel_kw, dict(bfs_kernel=True)),
+                       (base_kw, dict(max_iters=48)),
+                       (base_kw, dict(halo_mode="sparse")),
+                       (base_kw, dict(hub_count=8)),
+                       (base_kw, dict(halo_caps=(8, 32)))):
+        e2 = QueryEngine(idx, **{**base, **flip})
         e2.aot_warmup(idx, tmp_path)
         assert e2.aot_cache.hits == 0, f"stale AOT hit under {flip}"
         assert e2.aot_cache.stores > 0, flip

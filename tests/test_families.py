@@ -297,13 +297,14 @@ def test_grid_kernel_and_admit_plane_parity_with_il():
     uj, vj = jnp.asarray(u), jnp.asarray(v)
     ref = np.asarray(Q.label_verdicts(idx.packed, uj, vj, il=idx.il))
     got = np.asarray(QK.query_verdicts(idx.packed, uj, vj, il=idx.il,
-                                       q_block=128))
+                                       q_block=128, interpret=True))
     np.testing.assert_array_equal(ref, got)
     # streaming+il no longer raises: the dispatch falls back to the grid
     # kernel (StreamILFallbackWarning, bitwise-identical verdicts)
     with pytest.warns(QK.StreamILFallbackWarning, match="grid kernel"):
         via_stream = np.asarray(QK.query_verdicts(
-            idx.packed, uj, vj, il=idx.il, q_block=128, streaming=True))
+            idx.packed, uj, vj, il=idx.il, q_block=128, interpret=True,
+            streaming=True))
     np.testing.assert_array_equal(ref, via_stream)
     # admit plane: interval AND wraps the bit-plane kernel output
     q = min(64, len(u))
@@ -313,7 +314,7 @@ def test_grid_kernel_and_admit_plane_parity_with_il():
             idx.packed, uj[:q], vj[:q], n, il=idx.il, il_on=il_on))
         have = np.asarray(BK.admit_plane(
             idx.packed, uj[:q], vj[:q], il=idx.il, il_on=il_on,
-            n_block=128, q_block=32))
+            n_block=128, q_block=32, interpret=True))
         np.testing.assert_array_equal(want, have)
 
 

@@ -65,12 +65,13 @@ K = 64
 #: published size one v5e spends about 300 s on the build and 400 s on the
 #: full rebuild that ``mode="auto"`` picks after random deletions — each
 #: label fixpoint round scatters every edge's 64-lane row, about 0.85 s a
-#: round at 5M edges — and the run compiles for about 250 s more, so the
-#: stream does not fit 1200 s.  A quarter of the size (600k vertices, 1.25M
-#: edges) leaves about half the limit free.  The four-chip check compares
-#: layouts, whose equality does not depend on the size; it runs 1/64 of the
-#: size, because four chips cost four times as much per second.
-ONE_CHIP_CUT, FOUR_CHIP_CUT = 4, 64
+#: round at 5M edges — and the stream runs past 1200 s.  At half the size
+#: (1.2M vertices, 2.5M edges) the whole run takes about 310 s; sizes
+#: between half and full were not tried.  The four-chip check compares
+#: layouts, whose
+#: equality does not depend on the size; it runs 1/64 of the size, because
+#: four chips cost four times as much per second.
+ONE_CHIP_CUT, FOUR_CHIP_CUT = 2, 64
 
 #: the query/insert/delete stream both runs serve
 STREAM = dict(rounds=3, batch=16_384, insert_batch=1024, delete_batch=1024,

@@ -68,9 +68,8 @@ K = 64
 #: round at 5M edges — and the stream runs past 1200 s.  At half the size
 #: (1.2M vertices, 2.5M edges) the whole run takes about 310 s; sizes
 #: between half and full were not tried.  The four-chip check compares
-#: layouts, whose
-#: equality does not depend on the size; it runs 1/64 of the size, because
-#: four chips cost four times as much per second.
+#: layouts, whose equality does not depend on the size; it runs 1/64 of the
+#: size, because four chips cost four times as much per second.
 ONE_CHIP_CUT, FOUR_CHIP_CUT = 2, 64
 
 #: the query/insert/delete stream both runs serve

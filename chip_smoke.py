@@ -8,7 +8,7 @@ The graph is the Wiki row of the paper's Table 2 (published size 2.4M
 vertices, 5.0M edges), generated from ``--seed`` by
 ``graphs.generators.dag_like`` with its degree and back-edge share, and
 indexed with k = k' = 64.  Its scale is cut to fit the run's time limit
-(see ``ONE_CHIP_CUT``).  One process drives the normal entry points —
+(see ``ONE_CHIP_SCALE``).  One process drives the normal entry points —
 ``DBLIndex.build`` and ``ReachabilityServer`` over the engine's compiled
 Pallas kernels — through three phases:
 
@@ -61,16 +61,18 @@ from repro.serve.reach_server import ReachabilityServer  # noqa: E402
 WIKI_N, WIKI_M, WIKI_BACK_FRAC = 2_400_000, 5_000_000, 0.05
 K = 64
 
-#: Scale divisors of the Wiki graph (vertices and edges alike).  At the
-#: published size one v5e spends about 300 s on the build and 400 s on the
-#: full rebuild that ``mode="auto"`` picks after random deletions — each
-#: label fixpoint round scatters every edge's 64-lane row, about 0.85 s a
-#: round at 5M edges — and the stream runs past 1200 s.  At half the size
-#: (1.2M vertices, 2.5M edges) the whole run takes about 310 s; sizes
-#: between half and full were not tried.  The four-chip check compares
-#: layouts, whose equality does not depend on the size; it runs 1/64 of the
-#: size, because four chips cost four times as much per second.
-ONE_CHIP_CUT, FOUR_CHIP_CUT = 2, 64
+#: Fractions of the Wiki graph's published size (vertices and edges alike)
+#: that each run serves by default; ``--scale`` overrides them.  At the
+#: published size one v5e took 402 s to build, each insert batch took
+#: over 80 s, and the stream had not finished its rebuild after 880 s, so
+#: it does not fit the 1,200-s limit.  At 0.83 of the size (4.15M edges)
+#: the whole run took about 400 s with an empty compile cache; the label
+#: fixpoints slow down several-fold somewhere between 4.15M and 5.0M edges
+#: (PERF.md), and 0.83 is the largest fraction tried below that.  The
+#: four-chip check compares layouts, whose equality does not depend on the
+#: size; it runs 1/64 of the size, because four chips cost four times as
+#: much per second.
+ONE_CHIP_SCALE, FOUR_CHIP_SCALE = 0.83, 1 / 64
 
 #: the query/insert/delete stream both runs serve
 STREAM = dict(rounds=3, batch=16_384, insert_batch=1024, delete_batch=1024,
@@ -385,8 +387,16 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="1: the replicated layout on one chip; 4: the "
                          "vertex-sharded layout against the replicated one")
+    ap.add_argument("--scale", type=float, default=None,
+                    help="fraction of the Wiki graph's published size "
+                         f"(default {ONE_CHIP_SCALE} on one chip, "
+                         f"{FOUR_CHIP_SCALE} on four)")
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args(argv)
+    scale = a.scale if a.scale is not None else (
+        ONE_CHIP_SCALE if a.chips == 1 else FOUR_CHIP_SCALE)
+    if not 0 < scale <= 1:
+        ap.error(f"--scale {scale} is not in (0, 1]")
 
     devices = jax.devices()
     if devices[0].platform != "tpu":
@@ -398,10 +408,10 @@ def main(argv=None) -> int:
               f"finds {len(devices)}", file=sys.stderr)
         return 2
     log("compile cache:", enable_compile_cache())
-    cut = ONE_CHIP_CUT if a.chips == 1 else FOUR_CHIP_CUT
-    sizes = dict(n=WIKI_N // cut, m=WIKI_M // cut, seed=a.seed, **STREAM)
-    log(f"device: {devices[0].device_kind} x {len(devices)}; graph: Wiki/"
-        f"{cut} n={sizes['n']} m={sizes['m']}, k=k'={K}, seed {a.seed}")
+    sizes = dict(n=round(WIKI_N * scale), m=round(WIKI_M * scale),
+                 seed=a.seed, **STREAM)
+    log(f"device: {devices[0].device_kind} x {len(devices)}; graph: Wiki x "
+        f"{scale:g} n={sizes['n']} m={sizes['m']}, k=k'={K}, seed {a.seed}")
     t0 = time.perf_counter()
     if a.chips == 1:
         smoke_one_chip(backend="pallas", **sizes)

@@ -54,11 +54,15 @@ def main():
         ns = rng.integers(0, args.n, args.inserts).astype(np.int32)
         nd = rng.integers(0, args.n, args.inserts).astype(np.int32)
         server.insert(ns, nd)
+        server.engine.check_saturation()    # syncs the insert round counts
         s = server.stats.as_dict()
+        e = server.engine.stats.as_dict()
         print(f"round {r}: {s['queries']} queries served "
-              f"(ρ={s['rho']:.3f}), {s['inserts']} edges inserted, "
-              f"query {s['query_s']:.2f}s / insert {s['insert_s']:.2f}s "
-              f"cumulative")
+              f"(ρ={s['rho']:.3f}), {s['inserts']} edges inserted; "
+              f"cumulative: {e['bfs_levels']} BFS levels in "
+              f"{e['bfs_dispatches']} dispatches, lanes open "
+              f"{e['bfs_open_lane_levels']} of {e['bfs_slot_levels']} "
+              f"lane-levels, {e['insert_rounds']} insert rounds")
     es = server.engine_stats()
     print(f"engine: backend={es['backend']}, "
           f"{es['dispatch_shapes']} compiled dispatch shapes, "

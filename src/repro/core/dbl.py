@@ -64,6 +64,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from . import bitset
 from . import families as F
@@ -222,6 +223,21 @@ class DBLIndex(NamedTuple):
         (``core.families`` registry); each plug-in builds through its own
         hooks in its own plane repr.  ``il_dim``/``il_seed`` parameterize
         the interval family when enabled."""
+        return DBLIndex._build(
+            g, n_cap=n_cap, k=k, k_prime=k_prime, selection=selection,
+            leaf_r=leaf_r, max_iters=max_iters, check=check,
+            plane_repr=plane_repr, families=families, il_dim=il_dim,
+            il_seed=il_seed)[0]
+
+    @staticmethod
+    def _build(g: G.Graph, *, n_cap: int, k: int = 64, k_prime: int = 64,
+               selection: str = "product", leaf_r: int = 0,
+               max_iters: int = 256, check: str = "warn",
+               plane_repr: str = "bool", families=F.DEFAULT_FAMILIES,
+               il_dim: int = F.DEFAULT_IL_DIM, il_seed: int = 0
+               ) -> tuple["DBLIndex", jax.Array]:
+        """``build`` plus its fixpoints' relaxation rounds, summed (a ()
+        int32 on the device, ``update.rounds``)."""
         if check not in ("warn", "raise", "defer"):
             raise ValueError(f"unknown check mode {check!r}")
         P.check_plane_repr(plane_repr)
@@ -253,14 +269,15 @@ class DBLIndex(NamedTuple):
         # NB: a real copy, not asarray — label_del_epoch must not alias the
         # graph's del_epoch buffer (the engine's insert path donates the
         # graph; an aliased leaf would be invalidated with it)
-        return DBLIndex(g, landmarks, dl_in, dl_out, bl_in, bl_out, packed,
-                        sources, sinks,
-                        epoch=jnp.int32(0),
-                        label_del_epoch=jnp.array(g.del_epoch, jnp.int32),
-                        saturated=sat,
-                        il_in=None if il is None else il[0],
-                        il_out=None if il is None else il[1],
-                        il_seed=None if il is None else jnp.int32(il_seed))
+        idx = DBLIndex(g, landmarks, dl_in, dl_out, bl_in, bl_out, packed,
+                       sources, sinks,
+                       epoch=jnp.int32(0),
+                       label_del_epoch=jnp.array(g.del_epoch, jnp.int32),
+                       saturated=sat,
+                       il_in=None if il is None else il[0],
+                       il_out=None if il is None else il[1],
+                       il_seed=None if il is None else jnp.int32(il_seed))
+        return idx, U.rounds(jnp.concatenate(all_iters), max_iters)
 
     # ---- queries (Alg 2) --------------------------------------------------
     def query(self, u, v, *, bfs_chunk: int = 64, max_iters: int = 256,
@@ -379,6 +396,8 @@ class DBLIndex(NamedTuple):
         """``rebuild`` plus a report of what actually ran: ``(index, info)``
         where ``info["mode"]`` is the executed path (``"delta"``/``"full"``),
         ``info["reason"]`` one of ``"forced"``/``"estimate"``/``"saturated"``,
+        ``info["rounds"]`` the relaxation rounds its label fixpoints ran
+        (summed, a () int32 on the device: reading it waits for them),
         and — whenever a delta plan was computed — ``info["estimate"]`` the
         invalidation estimate the auto policy keys off.  The serving layer
         uses this to account delta vs full rebuilds separately."""
@@ -387,41 +406,52 @@ class DBLIndex(NamedTuple):
         full_kw = dict(selection=selection, leaf_r=leaf_r,
                        max_iters=max_iters, compact=compact, check=check,
                        plane_repr=plane_repr)
+
+        # each step reads device results back between its host steps, so
+        # its span is a wait (``repro.serve.spans``)
+        def full(**info):
+            with span("index.rebuild.wait_fixpoint"):
+                idx, rounds = self._full_rebuild(**full_kw)
+            return idx, {"mode": "full", **info, "rounds": rounds}
+
         if mode == "full":
-            return self._full_rebuild(**full_kw), \
-                {"mode": "full", "reason": "forced"}
-        if bool(np.asarray(self.saturated)):
+            return full(reason="forced")
+        with span("index.rebuild.wait_sat"):
+            saturated = bool(np.asarray(self.saturated))
+        if saturated:
             # a saturated index's labels are missing bits in an unknown
             # pattern: neither the clean region nor the invalidation
             # closure can be trusted, and a delta from them could launder
             # stale labels into saturated=False.  Rebuild honestly.
-            return self._full_rebuild(**full_kw), \
-                {"mode": "full", "reason": "saturated"}
-        plan = self._delta_plan(selection=selection, leaf_r=leaf_r)
+            return full(reason="saturated")
+        with span("index.rebuild.wait_plan"):
+            plan = self._delta_plan(selection=selection, leaf_r=leaf_r)
         est = plan["estimate"]
         if mode == "auto" and est["frac"] > delta_threshold:
-            return self._full_rebuild(**full_kw), \
-                {"mode": "full", "reason": "estimate", "estimate": est}
-        idx = self._delta_rebuild(plan, max_iters=max_iters,
-                                  compact=compact, check=check,
-                                  plane_repr=plane_repr)
+            return full(reason="estimate", estimate=est)
+        with span("index.rebuild.wait_fixpoint"):
+            idx, rounds = self._delta_rebuild(plan, max_iters=max_iters,
+                                              compact=compact, check=check,
+                                              plane_repr=plane_repr)
         reason = "forced" if mode == "delta" else "estimate"
-        return idx, {"mode": "delta", "reason": reason, "estimate": est}
+        return idx, {"mode": "delta", "reason": reason, "estimate": est,
+                     "rounds": rounds}
 
     def _full_rebuild(self, *, selection: str, leaf_r: int, max_iters: int,
-                      compact: bool, check: str,
-                      plane_repr: str = "bool") -> "DBLIndex":
+                      compact: bool, check: str, plane_repr: str = "bool"
+                      ) -> tuple["DBLIndex", jax.Array]:
+        """(index, rounds): Alg 1 over the live edges."""
         g = G.compact(self.graph) if compact else self.graph
         fam_kw = {}
         if self.il_in is not None:
             fam_kw = dict(families=self.families, il_dim=self.il_dim,
                           il_seed=self.il_seed)
-        idx = DBLIndex.build(g, n_cap=self.n_cap, k=self.k,
-                             k_prime=self.k_prime, selection=selection,
-                             leaf_r=leaf_r, max_iters=max_iters, check=check,
-                             plane_repr=plane_repr, **fam_kw)
+        idx, rounds = DBLIndex._build(
+            g, n_cap=self.n_cap, k=self.k, k_prime=self.k_prime,
+            selection=selection, leaf_r=leaf_r, max_iters=max_iters,
+            check=check, plane_repr=plane_repr, **fam_kw)
         return idx._replace(
-            epoch=jnp.asarray(self.epoch, jnp.int32) + jnp.int32(1))
+            epoch=jnp.asarray(self.epoch, jnp.int32) + jnp.int32(1)), rounds
 
     def _delta_plan(self, *, selection: str, leaf_r: int) -> dict:
         """Compute the invalidation frontier, the re-selected seed sets,
@@ -499,7 +529,8 @@ class DBLIndex(NamedTuple):
                 "estimate": estimate}
 
     def _delta_rebuild(self, plan: dict, *, max_iters: int, compact: bool,
-                       check: str, plane_repr: str = "bool") -> "DBLIndex":
+                       check: str, plane_repr: str = "bool"
+                       ) -> tuple["DBLIndex", jax.Array]:
         """Execute a delta plan: ONE fused fixpoint per propagation
         direction.
 
@@ -595,7 +626,9 @@ class DBLIndex(NamedTuple):
             epoch=jnp.asarray(self.epoch, jnp.int32) + jnp.int32(1),
             label_del_epoch=jnp.array(g2.del_epoch, jnp.int32),
             saturated=sat, il_in=il_in, il_out=il_out,
-            il_seed=self.il_seed)
+            il_seed=self.il_seed), U.rounds(
+                jnp.concatenate([jnp.atleast_1d(i) for i in iters]),
+                max_iters)
 
     # ---- introspection ----------------------------------------------------
     def label_bytes(self) -> int:

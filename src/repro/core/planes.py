@@ -1302,28 +1302,34 @@ def _sharded_bfs_impl(p, dlo_u, blin_v, blout_v, u, v, live, m_cut, m_total,
         lanes = jnp.arange(qc)
 
         def body(state):
-            fr, visited, hit, it = state
-            sf = hv[..., None] & fr[hs]                    # (d, H, Qc)
-            rf = jax.lax.all_to_all(sf, ax, 0, 0)
-            frc = jnp.concatenate([fr, rf.reshape(d * H, qc)], axis=0)
-            contrib = (frc[e_slot] & (live[e_gid] & e_valid)[:, None]
-                       & (e_gid[:, None] < m_cut[None, :]))
-            nxt = jax.ops.segment_max(contrib.astype(ftype), e_recv,
-                                      num_segments=n_loc) > 0
-            nxt = nxt & admit & ~visited & ~hit[None, :]
-            hit_loc = nxt[vloc, lanes] & owns_v
-            hit = hit | (jax.lax.psum(hit_loc.astype(jnp.int32), ax) > 0)
-            visited = visited | nxt
-            return nxt, visited, hit, it + 1
+            fr, visited, hit, it, hit_level = state
+            with jax.named_scope("bfs.gather"):
+                sf = hv[..., None] & fr[hs]                # (d, H, Qc)
+                rf = jax.lax.all_to_all(sf, ax, 0, 0)
+                frc = jnp.concatenate([fr, rf.reshape(d * H, qc)], axis=0)
+                contrib = (frc[e_slot] & (live[e_gid] & e_valid)[:, None]
+                           & (e_gid[:, None] < m_cut[None, :]))
+            with jax.named_scope("bfs.reduce"):
+                nxt = jax.ops.segment_max(contrib.astype(ftype), e_recv,
+                                          num_segments=n_loc) > 0
+            with jax.named_scope("bfs.update"):
+                nxt = nxt & admit & ~visited & ~hit[None, :]
+                hit_loc = nxt[vloc, lanes] & owns_v
+                new_hit = jax.lax.psum(hit_loc.astype(jnp.int32), ax) > 0
+                hit = hit | new_hit
+                visited = visited | nxt
+                hit_level = jnp.where(new_hit, it + 1, hit_level)
+            return nxt, visited, hit, it + 1, hit_level
 
         def cond(state):
-            fr, _, hit, it = state
+            fr, _, hit, it, _ = state
             alive = jax.lax.psum(fr.sum().astype(jnp.int32), ax) > 0
             return alive & (~hit.all()) & (it < max_iters)
 
-        _, _, hit, _ = jax.lax.while_loop(
-            cond, body, (frontier, visited, hit, jnp.int32(0)))
-        return hit
+        _, _, _, levels, hit_level = jax.lax.while_loop(
+            cond, body, (frontier, visited, hit, jnp.int32(0),
+                         jnp.zeros((qc,), jnp.int32)))
+        return Q.level_table(hit_level, levels)
 
     sm = shard_map(
         shard_body, mesh=mesh, check_vma=False,
@@ -1343,7 +1349,8 @@ def sharded_pruned_bfs(plan: ShardPlan, p: Q.PackedLabels,
                        m_total: jax.Array, dl_clean: jax.Array, *,
                        max_iters: int = 256,
                        frontier_dtype: str = "int8") -> jax.Array:
-    """(Qc,) bool — vertex-sharded twin of ``query.pruned_bfs``.
+    """(Qc, 2) int32 level table — vertex-sharded twin of
+    ``query.pruned_bfs(..., counted=True)``.
 
     The admit, frontier, and visited planes stay row-sharded; each round
     exchanges only the boundary frontier *bits* (one all_to_all over the
@@ -1351,7 +1358,9 @@ def sharded_pruned_bfs(plan: ShardPlan, p: Q.PackedLabels,
     liveness, per-lane hit bits).  Per-lane edge-count cutoffs and the DL
     prune gate behave exactly as in the replicated BFS, so hits are
     bitwise identical.  Dead lanes carry ``u == n_cap``: no shard owns that
-    id, so their frontier starts (and stays) empty."""
+    id, so their frontier starts (and stays) empty.  The level table
+    equals the replicated one entry for entry (``query.split_counted``
+    gives the hits)."""
     return _sharded_bfs_impl(
         p, rows.dlo_u, rows.blin_v, rows.blout_v,
         jnp.asarray(u, jnp.int32), jnp.asarray(v, jnp.int32), live,

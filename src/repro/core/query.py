@@ -306,7 +306,7 @@ FRONTIER_DTYPES = {"int8": jnp.int8, "int32": jnp.int32,
 
 
 def _pruned_bfs_packed(g, p, u, v, admit, m_cut, dl_on, il=None, il_on=None,
-                       *, n_cap, max_iters):
+                       *, n_cap, max_iters, counted=False):
     """Word-packed BFS lanes: (n_cap, Wq) uint32 planes, Wq = ceil(Qc/32).
 
     Identical round structure to the lane-wise loop — gather frontier words
@@ -336,38 +336,69 @@ def _pruned_bfs_packed(g, p, u, v, admit, m_cut, dl_on, il=None, il_on=None,
     lb = (lanes % 32).astype(jnp.uint32)
 
     def cond(state):
-        fw, _, hw, it = state
+        fw, _, hw, it = state[:4]
         done = jnp.all((hw & lane_mask) == lane_mask)
         return jnp.logical_and(jnp.any(fw != 0),
                                jnp.logical_and(~done, it < max_iters))
 
     def body(state):
-        fw, vw, hw, it = state
-        contrib = jnp.where(live_s[:, None], fw[src_s], jnp.uint32(0))
-        if m_cut is not None:
-            contrib &= cut_ws
-        nw = bitset.sorted_segment_or(contrib, dst_s, n_cap)
-        nw = nw & admit_w & ~vw & ~hw[None, :]
-        rows = nw[v]                                   # (qc, Wq)
-        hits = ((rows[lanes, lw] >> lb) & jnp.uint32(1)).astype(jnp.bool_)
-        hw = hw | bitset.pack(hits)
-        vw = vw | nw
+        fw, vw, hw, it = state[:4]
+        with jax.named_scope("bfs.gather"):
+            contrib = jnp.where(live_s[:, None], fw[src_s], jnp.uint32(0))
+            if m_cut is not None:
+                contrib &= cut_ws
+        with jax.named_scope("bfs.reduce"):
+            nw = bitset.sorted_segment_or(contrib, dst_s, n_cap)
+        with jax.named_scope("bfs.update"):
+            nw = nw & admit_w & ~vw & ~hw[None, :]
+            rows = nw[v]                               # (qc, Wq)
+            hits = ((rows[lanes, lw] >> lb) & jnp.uint32(1)).astype(jnp.bool_)
+            hw = hw | bitset.pack(hits)
+            vw = vw | nw
+            if counted:
+                # hits are new: nw was masked by the lanes already hit
+                return nw, vw, hw, it + 1, jnp.where(hits, it + 1, state[4])
         return nw, vw, hw, it + 1
 
-    _, _, hit_w, _ = jax.lax.while_loop(
-        cond, body, (frontier_w, visited_w, hit_w, jnp.int32(0)))
-    return bitset.unpack(hit_w, qc)
+    state = (frontier_w, visited_w, hit_w, jnp.int32(0))
+    if counted:
+        state += (jnp.zeros((qc,), jnp.int32),)
+    state = jax.lax.while_loop(cond, body, state)
+    if counted:
+        return level_table(state[4], state[3])
+    return bitset.unpack(state[2], qc)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_cap", "max_iters", "frontier_dtype"))
+def level_table(hit_level: jax.Array, levels: jax.Array) -> jax.Array:
+    """(Qc, 2) int32 — per lane, the level at which it hit (0: never) and
+    the level at which it was answered: its hit level, else ``levels``,
+    the loop's count at exit (an unreachable lane is answered only when
+    the loop ends).  A row per lane, like the hits it stands for: the
+    answer columns are what the open-lane counters sum, and the loop's
+    level count is their largest entry."""
+    return jnp.stack([hit_level, jnp.where(hit_level > 0, hit_level, levels)],
+                     axis=1)
+
+
+def split_counted(table: jax.Array):
+    """``(hit, hit_level, levels)`` from ``pruned_bfs(..., counted=True)``'s
+    (Qc, 2) level table.  ``levels`` is the largest answer level: the loop
+    ends at its last lane's hit, or with lanes that never hit, which are
+    answered at its last level."""
+    hit_level = table[:, 0]
+    return hit_level > 0, hit_level, jnp.max(table[:, 1])
+
+
+@functools.partial(jax.jit, static_argnames=("n_cap", "max_iters",
+                                             "frontier_dtype", "counted"))
 def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
                admit: jax.Array | None = None,
                m_cut: jax.Array | None = None,
                dl_clean: jax.Array | None = None,
                il=None,
                *, n_cap: int, max_iters: int = 256,
-               frontier_dtype: str = "int8") -> jax.Array:
+               frontier_dtype: str = "int8",
+               counted: bool = False) -> jax.Array:
     """(Qc,) bool — resolve unknown queries by label-pruned BFS lanes.
 
     ``admit`` lets callers supply a precomputed (n_cap, Qc) admit plane
@@ -405,6 +436,12 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
     into the admit plane.  It is insert-monotone like BL (no per-lane
     ``m_cut`` gate) but not deletion-sound, so it shares the ``dl_clean``
     tombstone gate — a dirty dispatch drops it for every lane.
+
+    ``counted=True`` also counts levels (one level = one relaxation round
+    of the loop) and returns, in place of the hits, the (Qc, 2) int32
+    ``level_table``: row q is lane q's (hit level, answer level), nonzero
+    in its first entry exactly when lane q hit.  ``split_counted`` turns
+    it into ``(hit, hit_level, levels)``; the hits are the same bits.
     """
     ftype = FRONTIER_DTYPES[frontier_dtype]
     qc = u.shape[0]
@@ -419,7 +456,8 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
         else jnp.broadcast_to(clean, u.shape)
     if frontier_dtype == "packed":
         return _pruned_bfs_packed(g, p, u, v, admit, m_cut, dl_on, il, il_on,
-                                  n_cap=n_cap, max_iters=max_iters)
+                                  n_cap=n_cap, max_iters=max_iters,
+                                  counted=counted)
     if admit is None:
         admit = _admit_plane(p, u, v, n_cap, dl_on, il, il_on)  # (n_cap, Qc)
     elif admit.dtype != jnp.bool_:
@@ -433,27 +471,38 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
     lanes = jnp.arange(qc)
 
     def cond(state):
-        frontier, _, hit, it = state
+        frontier, _, hit, it = state[:4]
         return jnp.logical_and(frontier.any(),
                                jnp.logical_and(~hit.all(), it < max_iters))
 
     def body(state):
-        frontier, visited, hit, it = state
-        contrib = frontier[g.src] & live[:, None]
-        if m_cut is not None:
-            # fused into the contrib elementwise op each iteration — no
-            # persistent (m_cap, Qc) mask carried across the while-loop
-            contrib &= eids[:, None] < m_cut[None, :]
-        nxt = jax.ops.segment_max(contrib.astype(ftype), g.dst,
-                                  num_segments=n_cap) > 0
-        nxt = nxt & admit & ~visited & ~hit[None, :]
-        hit = hit | nxt[v, lanes]
-        visited = visited | nxt
+        frontier, visited, hit, it = state[:4]
+        with jax.named_scope("bfs.gather"):
+            contrib = frontier[g.src] & live[:, None]
+            if m_cut is not None:
+                # fused into the contrib elementwise op each iteration — no
+                # persistent (m_cap, Qc) mask carried across the while-loop
+                contrib &= eids[:, None] < m_cut[None, :]
+        with jax.named_scope("bfs.reduce"):
+            nxt = jax.ops.segment_max(contrib.astype(ftype), g.dst,
+                                      num_segments=n_cap) > 0
+        with jax.named_scope("bfs.update"):
+            nxt = nxt & admit & ~visited & ~hit[None, :]
+            new_hit = nxt[v, lanes]       # new: nxt was masked by ~hit
+            hit = hit | new_hit
+            visited = visited | nxt
+            if counted:
+                return (nxt, visited, hit, it + 1,
+                        jnp.where(new_hit, it + 1, state[4]))
         return nxt, visited, hit, it + 1
 
-    _, _, hit, _ = jax.lax.while_loop(
-        cond, body, (frontier, visited, hit, jnp.int32(0)))
-    return hit
+    state = (frontier, visited, hit, jnp.int32(0))
+    if counted:
+        state += (jnp.zeros((qc,), jnp.int32),)
+    state = jax.lax.while_loop(cond, body, state)
+    if counted:
+        return level_table(state[4], state[3])
+    return state[2]
 
 
 def query(g: Graph, p: PackedLabels, u, v, *, n_cap: int,

@@ -109,6 +109,13 @@ def saturated(iters: jax.Array, max_iters: int) -> jax.Array:
     return jnp.any(iters > jnp.int32(max_iters))
 
 
+def rounds(iters: jax.Array, max_iters: int) -> jax.Array:
+    """() int32 — the relaxation rounds a set of fixpoints ran, summed
+    (they run one after another).  A fixpoint cut off at ``max_iters``
+    reports ``max_iters + 1`` (``saturated``) but ran ``max_iters``."""
+    return jnp.minimum(jnp.asarray(iters, jnp.int32), max_iters).sum()
+
+
 @jax.jit
 def delete_and_mark(g: G.Graph, del_src: jax.Array, del_dst: jax.Array,
                     epoch: jax.Array | int = 0):

@@ -108,6 +108,7 @@ from repro.core.dbl import (DBLIndex, LabelSaturationWarning,
 from repro.kernels.dbl_query.ops import (StreamILFallbackWarning,
                                          verdicts_device)
 from repro.kernels.bfs_prune.ops import admit_plane as bfs_admit_plane_op
+from repro.serve.spans import span
 
 #: supported consistency modes (``"latest-snapshot"`` is an alias)
 CONSISTENCY_MODES = ("as-of-submit", "latest")
@@ -119,6 +120,11 @@ CONSISTENCY_MODES = ("as-of-submit", "latest")
 #:               ``flush_watermark`` lanes (right-sized dispatches without
 #:               unbounded deferral on unknown-heavy streams).
 FLUSH_POLICIES = (None, "deadline", "watermark")
+
+#: version of the query executables' outputs, part of their AOT cache tags
+#: (``aot_warmup``): an entry exported under an older output schema must
+#: miss, never load.  2: ``coalesced`` returns (answers, hit_level, levels)
+OUTPUT_SCHEMA = 2
 
 
 def select_backend(backend: str = "auto") -> str:
@@ -180,6 +186,28 @@ class EngineStats:
     halo_bytes: int = 0
     halo_rounds: int = 0
     quiet_pair_rounds: int = 0
+    # BFS residue level counts, read from every coalesced dispatch in the
+    # flush's one host transfer of its results: ``bfs_levels`` sums each
+    # dispatch's level count (its loop's rounds), ``bfs_slot_levels`` the
+    # levels times the dispatch's lanes, ``bfs_open_lane_levels`` the
+    # levels each lane that rode the BFS stayed open (its hit level, or the
+    # dispatch's last level if it never hit), ``bfs_proof_levels`` the
+    # levels after a dispatch's last hit, which only prove its remaining
+    # lanes unreachable
+    bfs_levels: int = 0
+    bfs_slot_levels: int = 0
+    bfs_open_lane_levels: int = 0
+    bfs_proof_levels: int = 0
+    # label fixpoint rounds: ``insert_rounds`` sums the relaxation rounds
+    # of the insert program's four plane fixpoints over the
+    # ``insert_rounds_batches`` insert batches synced so far (with the
+    # saturation flags, at flush boundaries: later inserts wait for the
+    # next flush); ``rebuild_rounds`` those of every rebuild's fixpoints
+    # (replicated layout: the vertex-sharded fixpoints count theirs in
+    # ``halo_rounds``)
+    insert_rounds: int = 0
+    insert_rounds_batches: int = 0
+    rebuild_rounds: int = 0
     #: per-family prune attribution over every resolved lane: "dl" counts
     #: label positives (Lemma 1 + self-queries), "bl"/"il" count negative
     #: lanes charged to BL containment / interval containment (first
@@ -203,6 +231,13 @@ class EngineStats:
                 "halo_bytes": self.halo_bytes,
                 "halo_rounds": self.halo_rounds,
                 "quiet_pair_rounds": self.quiet_pair_rounds,
+                "bfs_levels": self.bfs_levels,
+                "bfs_slot_levels": self.bfs_slot_levels,
+                "bfs_open_lane_levels": self.bfs_open_lane_levels,
+                "bfs_proof_levels": self.bfs_proof_levels,
+                "insert_rounds": self.insert_rounds,
+                "insert_rounds_batches": self.insert_rounds_batches,
+                "rebuild_rounds": self.rebuild_rounds,
                 "prune_hits": dict(self.prune_hits)}
 
 
@@ -249,7 +284,8 @@ class _Pending:
         int32 D2H the label phase owes) — the watermark policy and the
         flush path share the memo instead of re-blocking per check."""
         if self._nu is None:
-            self._nu = min(int(self.n_unknown), self.q)
+            with span("engine.wait_nu"):
+                self._nu = min(int(self.n_unknown), self.q)
         return self._nu
 
     def resolve(self) -> np.ndarray:
@@ -386,8 +422,9 @@ class QueryEngine:
         # resolve them against the lineage they belong to before the engine
         # lets go of it (older snapshots' buffers may already be donated)
         self._inflight: list = []
-        # deferred saturation flags (one () bool per insert); drained at
-        # flush boundaries so the insert path never forces a host sync
+        # deferred (saturated, rounds) device scalars, one pair per insert;
+        # drained at flush boundaries so the insert path never forces a
+        # host sync (rounds is None on the vertex-sharded layout)
         self._sat_flags: list = []
         self._build_executables()
         if index is not None:
@@ -434,8 +471,9 @@ class QueryEngine:
                                            hub_count=self.hub_count)
         self._index = idx
         if idx is not None:
-            self.epoch = int(np.asarray(idx.epoch))
-            self._m_now = int(idx.graph.m)
+            with span("engine.wait_bind"):
+                self.epoch = int(np.asarray(idx.epoch))
+                self._m_now = int(idx.graph.m)
         else:
             self.epoch = 0
             self._m_now = 0
@@ -450,7 +488,8 @@ class QueryEngine:
         shares the dispatch's tombstone set."""
         stale = self._unresolved_inflight()
         if stale:
-            self.flush(stale)
+            with span("engine.drain"):
+                self.flush(stale)
         self._inflight = []
 
     # ------------------------------------------------------------ compile
@@ -541,18 +580,26 @@ class QueryEngine:
                                            jnp.int32(0), ~d_stale,
                                            il_rows=il_rows)
             counts = Q.verdict_counts(verd, rows, il_rows)
-            unknown = verd == jnp.int8(-1)
-            n_unknown = unknown.sum().astype(jnp.int32)
-            rank_u = jnp.cumsum(unknown.astype(jnp.int32))
-            rank_k = jnp.cumsum((~unknown).astype(jnp.int32))
-            pos = jnp.where(unknown, rank_u - 1, n_unknown + rank_k - 1)
-            q = u.shape[0]
-            lanes = jnp.arange(q, dtype=jnp.int32)
-            order = jnp.zeros(q, jnp.int32).at[pos].set(lanes)
-            u_c = jnp.zeros(q, jnp.int32).at[pos].set(u)
-            v_c = jnp.zeros(q, jnp.int32).at[pos].set(v)
+            with jax.named_scope("label.compact"):
+                unknown = verd == jnp.int8(-1)
+                n_unknown = unknown.sum().astype(jnp.int32)
+                rank_u = jnp.cumsum(unknown.astype(jnp.int32))
+                rank_k = jnp.cumsum((~unknown).astype(jnp.int32))
+                pos = jnp.where(unknown, rank_u - 1, n_unknown + rank_k - 1)
+                q = u.shape[0]
+                lanes = jnp.arange(q, dtype=jnp.int32)
+                order = jnp.zeros(q, jnp.int32).at[pos].set(lanes)
+                u_c = jnp.zeros(q, jnp.int32).at[pos].set(u)
+                v_c = jnp.zeros(q, jnp.int32).at[pos].set(v)
             answers = verd == jnp.int8(1)
             return answers, order, u_c, v_c, n_unknown, counts
+
+        def residue_outputs(verd, live_lane, need, table):
+            """The coalesced phase's three outputs (every layout's), from
+            the re-check verdicts and the BFS's level table."""
+            hit, hit_level, levels = Q.split_counted(table)
+            answers = ((verd == jnp.int8(1)) & live_lane) | hit
+            return answers, jnp.where(need, hit_level, -1), levels
 
         def make_coalesced_phase(chunk: int):
             def coalesced(g: Q.Graph, p: Q.PackedLabels, il, uu, vv, m_cut,
@@ -587,20 +634,26 @@ class QueryEngine:
                 ``il`` (or None) joins the re-check the same way it joins
                 the label phase — insert-monotone, so coalesced stale lanes
                 keep it without an edge-count gate — and threads into the
-                residue BFS admit planes under the tombstone-clean gate."""
+                residue BFS admit planes under the tombstone-clean gate.
+
+                Returns ``(answers, hit_level, levels)``: the (chunk,) bool
+                answers, the BFS's per-lane hit level (``split_counted``;
+                -1 on lanes that did not ride the BFS: padding and lanes
+                the re-check answered) and its level count."""
                 n_cap = p.dl_in.shape[0]
                 live_lane = uu < jnp.int32(n_cap)
                 uu_safe = jnp.minimum(uu, jnp.int32(n_cap - 1))
-                if backend in ("pallas", "pallas-interpret"):
-                    verd = verdicts_device(
-                        p, uu_safe, vv, m_cut, g.m,
-                        _d_cut_vec(d_stale, uu.shape), jnp.int32(1), il,
-                        q_block=min(q_block, chunk),
-                        interpret=interpret, out_dtype=out_dtype,
-                        streaming=verdict_streaming(il))
-                else:
-                    verd = Q.cut_verdicts(p, uu_safe, vv, m_cut, g.m,
-                                          ~d_stale, il=il)
+                with jax.named_scope("bfs.recheck"):
+                    if backend in ("pallas", "pallas-interpret"):
+                        verd = verdicts_device(
+                            p, uu_safe, vv, m_cut, g.m,
+                            _d_cut_vec(d_stale, uu.shape), jnp.int32(1), il,
+                            q_block=min(q_block, chunk),
+                            interpret=interpret, out_dtype=out_dtype,
+                            streaming=verdict_streaming(il))
+                    else:
+                        verd = Q.cut_verdicts(p, uu_safe, vv, m_cut, g.m,
+                                              ~d_stale, il=il)
                 need = live_lane & (verd == jnp.int8(-1))
                 uu2 = jnp.where(need, uu, jnp.int32(n_cap))
                 admit = None
@@ -613,10 +666,11 @@ class QueryEngine:
                         n_block=min(1024, max(8, n_cap)),
                         q_block=min(128, chunk), interpret=interpret,
                         out_dtype=jnp.int8, streaming=streaming)
-                hit = Q.pruned_bfs(g, p, uu2, vv, admit, m_cut, ~d_stale,
-                                   il, n_cap=n_cap, max_iters=max_iters,
-                                   frontier_dtype=frontier_dtype)
-                return ((verd == jnp.int8(1)) & live_lane) | hit
+                table = Q.pruned_bfs(g, p, uu2, vv, admit, m_cut, ~d_stale,
+                                     il, n_cap=n_cap, max_iters=max_iters,
+                                     frontier_dtype=frontier_dtype,
+                                     counted=True)
+                return residue_outputs(verd, live_lane, need, table)
             return coalesced
 
         def make_coalesced_sharded(chunk: int):
@@ -642,22 +696,23 @@ class QueryEngine:
                 n_cap = p.dl_in.shape[0]
                 live_lane = uu < jnp.int32(n_cap)
                 uu_safe = jnp.minimum(uu, jnp.int32(n_cap - 1))
-                rows = PL.sharded_rows(p, uu_safe, vv, mesh=vertex_mesh)
-                il_rows = None if il is None else \
-                    PL.sharded_il_rows(il, uu_safe, vv, mesh=vertex_mesh)
-                verd = Q.cut_verdicts_rows(rows, uu_safe, vv, m_cut, g.m,
-                                           ~d_stale, il_rows=il_rows)
+                with jax.named_scope("bfs.recheck"):
+                    rows = PL.sharded_rows(p, uu_safe, vv, mesh=vertex_mesh)
+                    il_rows = None if il is None else \
+                        PL.sharded_il_rows(il, uu_safe, vv, mesh=vertex_mesh)
+                    verd = Q.cut_verdicts_rows(rows, uu_safe, vv, m_cut, g.m,
+                                               ~d_stale, il_rows=il_rows)
                 need = live_lane & (verd == jnp.int8(-1))
                 uu2 = jnp.where(need, uu, jnp.int32(n_cap))
                 plan = PL.ShardPlan(
                     vertex_mesh, n_cap, 0,
                     PL._DirPlan(e_slot, e_recv, e_gid, e_valid, h_send,
                                 h_valid, e_start, e_tail), None)
-                hit = PL.sharded_pruned_bfs(
+                table = PL.sharded_pruned_bfs(
                     plan, p, rows, uu2, vv, edge_mask(g), m_cut, g.m,
                     ~d_stale, max_iters=max_iters,
                     frontier_dtype=frontier_dtype)
-                return ((verd == jnp.int8(1)) & live_lane) | hit
+                return residue_outputs(verd, live_lane, need, table)
             return coalesced
 
         if vertex_mesh is not None:
@@ -689,7 +744,8 @@ class QueryEngine:
                 g, dl_in, dl_out, bl_in, bl_out, ns, nd, epoch,
                 n_cap=n_cap, max_iters=max_iters, plane_repr=plane_repr)
             sat = U.saturated(iters, max_iters)
-            return g2, a, b, c, d, Q.pack_labels(a, b, c, d), epoch2, sat
+            return (g2, a, b, c, d, Q.pack_labels(a, b, c, d), epoch2, sat,
+                    U.rounds(iters, max_iters))
 
         donate_ins = (0, 1, 2, 3, 4) if self.donate else ()
         self._insert_fn = jax.jit(insert_impl, donate_argnums=donate_ins)
@@ -750,14 +806,15 @@ class QueryEngine:
             raise ValueError(
                 "vertex-sharded engines serve only their bound index; "
                 "bind the snapshot first (engine.index = idx)")
-        uj, vj, q = self._pad_queries(u, v)
-        if self.mesh is not None:
-            from repro.launch.sharding import reach_query_shardings
-            qsh, _ = reach_query_shardings(self.mesh)
-            uj = jax.device_put(uj, qsh)
-            vj = jax.device_put(vj, qsh)
-        answers, order, u_c, v_c, n_unknown, counts = self._label_phase(
-            index.packed, index.il, uj, vj, index.dirty_flag)
+        with span("engine.label_dispatch"):
+            uj, vj, q = self._pad_queries(u, v)
+            if self.mesh is not None:
+                from repro.launch.sharding import reach_query_shardings
+                qsh, _ = reach_query_shardings(self.mesh)
+                uj = jax.device_put(uj, qsh)
+                vj = jax.device_put(vj, qsh)
+            answers, order, u_c, v_c, n_unknown, counts = self._label_phase(
+                index.packed, index.il, uj, vj, index.dirty_flag)
         if self._index is not None and index is self._index:
             tag = dict(lineage=self._lineage, epoch=self.epoch,
                        m_at_submit=self._m_now)
@@ -860,33 +917,37 @@ class QueryEngine:
     def _finish_group(self, grp, results, mode, engine_group):
         infos = [(i, p, p.nu) for i, p in grp]   # p.nu memoizes the sync
         total = sum(nu for _, _, nu in infos)
-        hits_all = np.zeros(0, np.bool_)
+        parts, chunk = [], 0
         if total:
             index = self._index if engine_group else grp[0][1].index
             n_cap = index.packed.dl_in.shape[0]
-            uu = np.concatenate([np.asarray(p.u_c)[:nu]
-                                 for _, p, nu in infos if nu])
-            vv = np.concatenate([np.asarray(p.v_c)[:nu]
-                                 for _, p, nu in infos if nu])
-            if engine_group and mode == "as-of-submit":
-                cuts = np.concatenate([
-                    np.full(nu, p.m_at_submit, np.int32)
-                    for _, p, nu in infos if nu])
-                self.stats.stale_lanes += int((cuts < self._m_now).sum())
-            else:
-                # latest consistency / foreign snapshot group: every lane
-                # sees the group's full edge set and keeps the DL prune
-                cuts = np.full(total, Q.FRESH_CUT, np.int32)
-            chunk = (self.bfs_chunk if total > self.bfs_chunk
-                     else self._bucket_for(total))
-            pad = -total % chunk
-            if pad:
-                # dead lanes: out-of-range source -> empty frontier; fresh
-                # cutoff so they never ride the stale path
-                uu = np.concatenate([uu, np.full(pad, n_cap, np.int32)])
-                vv = np.concatenate([vv, np.zeros(pad, np.int32)])
-                cuts = np.concatenate([cuts,
-                                       np.full(pad, Q.FRESH_CUT, np.int32)])
+            with span("engine.wait_residue"):
+                ends = jax.device_get([(p.u_c, p.v_c)
+                                       for _, p, nu in infos if nu])
+            with span("engine.pool"):
+                nus = [nu for _, _, nu in infos if nu]
+                uu = np.concatenate([u[:nu] for (u, _), nu in zip(ends, nus)])
+                vv = np.concatenate([v[:nu] for (_, v), nu in zip(ends, nus)])
+                if engine_group and mode == "as-of-submit":
+                    cuts = np.concatenate([
+                        np.full(nu, p.m_at_submit, np.int32)
+                        for _, p, nu in infos if nu])
+                    self.stats.stale_lanes += int((cuts < self._m_now).sum())
+                else:
+                    # latest consistency / foreign snapshot group: every
+                    # lane sees the group's full edge set and keeps the DL
+                    # prune
+                    cuts = np.full(total, Q.FRESH_CUT, np.int32)
+                chunk = (self.bfs_chunk if total > self.bfs_chunk
+                         else self._bucket_for(total))
+                pad = -total % chunk
+                if pad:
+                    # dead lanes: out-of-range source -> empty frontier;
+                    # fresh cutoff so they never ride the stale path
+                    uu = np.concatenate([uu, np.full(pad, n_cap, np.int32)])
+                    vv = np.concatenate([vv, np.zeros(pad, np.int32)])
+                    cuts = np.concatenate(
+                        [cuts, np.full(pad, Q.FRESH_CUT, np.int32)])
             fn = self._coal_phases[chunk]
             d_stale = jnp.asarray(index.dirty_flag)
             extra = self._coalesced_extra_args() if engine_group else ()
@@ -895,43 +956,62 @@ class QueryEngine:
                     "vertex-sharded engines resolve only batches submitted "
                     "against their bound index (the shard plan is "
                     "lineage-scoped)")
-            hit_parts = []
-            for start in range(0, total, chunk):
-                hit_parts.append(fn(index.graph, index.packed, index.il,
+            with span("engine.bfs_dispatch"):
+                for start in range(0, total, chunk):
+                    parts.append(fn(index.graph, index.packed, index.il,
                                     jnp.asarray(uu[start:start + chunk]),
                                     jnp.asarray(vv[start:start + chunk]),
                                     jnp.asarray(cuts[start:start + chunk]),
                                     d_stale, *extra))
-                self.stats.bfs_dispatches += 1
-            # all chunks are enqueued before the first D2H forces a wait
-            hits_all = np.concatenate([np.asarray(h)
-                                       for h in hit_parts])[:total]
-        off = 0
-        for i, p, nu in infos:
-            ans = np.array(p.answers)      # writable host copy
-            if nu:
-                order = np.asarray(p.order)[:nu]
-                ans[order] = hits_all[off:off + nu]
-                off += nu
-            out = ans[:p.q]
-            p._result = out
-            results[i] = out
-            self.stats.queries += p.q
-            self.stats.batches += 1
-            self.stats.bfs_answered += nu
-            self.stats.label_answered += p.q - nu
-            if p.counts is not None:
-                # padding lanes are vertex-0 self-queries: always label
-                # positives, charged to "dl" on device — back them out so
-                # the attribution covers exactly the p.q real lanes
-                dl, bl, il, thm = (int(x) for x in np.asarray(p.counts))
-                pad = int(np.asarray(p.answers).shape[0]) - p.q
-                ph = self.stats.prune_hits
-                ph["dl"] += dl - pad
-                ph["bl"] += bl
-                ph["il"] += il
-                ph["thm"] += thm
-                ph["bfs"] += nu
+                    self.stats.bfs_dispatches += 1
+        # all chunks are enqueued before this one transfer waits for them;
+        # the label phase's outputs ride along (long since computed)
+        with span("engine.wait_hits"):
+            parts, labels = jax.device_get(
+                (parts, [(p.answers, p.order, p.counts) for _, p, _ in infos]))
+        with span("engine.scatter"):
+            hits_all = np.zeros(0, np.bool_)
+            if parts:
+                hits_all = np.concatenate([a for a, _, _ in parts])[:total]
+            self._count_levels(parts, chunk)
+            off = 0
+            for (i, p, nu), (answers, order, counts) in zip(infos, labels):
+                ans = np.array(answers)      # writable host copy
+                if nu:
+                    ans[order[:nu]] = hits_all[off:off + nu]
+                    off += nu
+                out = ans[:p.q]
+                p._result = out
+                results[i] = out
+                self.stats.queries += p.q
+                self.stats.batches += 1
+                self.stats.bfs_answered += nu
+                self.stats.label_answered += p.q - nu
+                if counts is not None:
+                    # padding lanes are vertex-0 self-queries: always label
+                    # positives, charged to "dl" on device — back them out
+                    # so the attribution covers exactly the p.q real lanes
+                    dl, bl, il, thm = (int(x) for x in counts)
+                    pad = answers.shape[0] - p.q
+                    ph = self.stats.prune_hits
+                    ph["dl"] += dl - pad
+                    ph["bl"] += bl
+                    ph["il"] += il
+                    ph["thm"] += thm
+                    ph["bfs"] += nu
+
+    def _count_levels(self, parts, chunk: int):
+        """Add the coalesced dispatches' level counts (host copies of
+        their ``(answers, hit_level, levels)``) to the BFS counters."""
+        st = self.stats
+        for _, hit_level, levels in parts:
+            levels = int(levels)
+            rode = hit_level[hit_level >= 0]
+            st.bfs_levels += levels
+            st.bfs_slot_levels += levels * chunk
+            st.bfs_open_lane_levels += int(
+                np.where(rode > 0, rode, levels).sum())
+            st.bfs_proof_levels += levels - int(rode.max(initial=0))
 
     def run(self, index: DBLIndex, u, v, *, return_stats: bool = False):
         """Full Alg 2 on ``index`` for one batch; returns (Q,) np.bool_."""
@@ -942,8 +1022,7 @@ class QueryEngine:
         pend = self.submit(index, u, v)
         ans = pend.resolve()
         if return_stats:
-            nu = min(int(pend.n_unknown), q)
-            return ans, {"rho": 1.0 - nu / q, "n_bfs": nu}
+            return ans, {"rho": 1.0 - pend.nu / q, "n_bfs": pend.nu}
         return ans
 
     # ------------------------------------------------------ bound serving
@@ -962,9 +1041,14 @@ class QueryEngine:
         owns its index; callers must not retain old references."""
         if self._index is None:
             raise ValueError("engine has no bound index; use run()")
+        with span("engine.insert_dispatch"):
+            return self._insert(new_src, new_dst)
+
+    def _insert(self, new_src, new_dst) -> DBLIndex:
         idx = self._index
         ns = jnp.asarray(np.asarray(new_src, np.int32))
         nd = jnp.asarray(np.asarray(new_dst, np.int32))
+        rounds = None
         if self.vertex_mesh is not None:
             from repro.core import distributed as D
             # sharded Alg-3: psum'd seed rows + halo fixpoint; the plan is
@@ -977,7 +1061,7 @@ class QueryEngine:
                 telemetry=self._halo_telemetry)
             self._index = idx2._replace(epoch=jnp.int32(self.epoch + 1))
         else:
-            g2, a, b, c, d, packed, epoch2, sat = self._insert_fn(
+            g2, a, b, c, d, packed, epoch2, sat, rounds = self._insert_fn(
                 idx.graph, idx.dl_in, idx.dl_out, idx.bl_in, idx.bl_out,
                 ns, nd, jnp.int32(self.epoch))
             il_kw = {}
@@ -997,7 +1081,8 @@ class QueryEngine:
                 graph=g2, dl_in=a, dl_out=b, bl_in=c, bl_out=d,
                 packed=packed, epoch=epoch2,
                 saturated=jnp.asarray(idx.saturated) | sat, **il_kw)
-        self._sat_flags.append(sat)   # checked lazily at flush boundaries
+        # checked lazily at flush boundaries
+        self._sat_flags.append((sat, rounds))
         self.epoch += 1
         self._m_now += int(ns.size)
         self.stats.inserts += int(ns.size)
@@ -1059,6 +1144,9 @@ class QueryEngine:
         else:
             new_idx, info = self._index.rebuild_info(**build_kw)
             self.index = new_idx      # property setter: drain + new lineage
+            with span("engine.wait_rebuild"):
+                info["rounds"] = int(info["rounds"])
+            self.stats.rebuild_rounds += info["rounds"]
         self.stats.rebuilds += 1
         if info["mode"] == "delta":
             self.stats.delta_rebuilds += 1
@@ -1077,11 +1165,19 @@ class QueryEngine:
         return d
 
     def check_saturation(self, *, warn: bool = True) -> int:
-        """Drain the deferred per-insert saturation flags (syncs them) and
-        return how many insert batches saturated; optionally warns.  Called
-        automatically at every ``flush()``."""
+        """Drain the deferred per-insert saturation flags and round counts
+        (one sync for all of them), add the rounds to
+        ``stats.insert_rounds`` (and their batches to
+        ``insert_rounds_batches``) and return how many insert batches
+        saturated; optionally warns.  Called automatically at every
+        ``flush()``."""
         flags, self._sat_flags = self._sat_flags, []
-        n = sum(bool(np.asarray(f)) for f in flags)
+        with span("engine.wait_sat"):
+            flags = jax.device_get(flags)
+        n = sum(bool(sat) for sat, _ in flags)
+        rounds = [int(r) for _, r in flags if r is not None]
+        self.stats.insert_rounds += sum(rounds)
+        self.stats.insert_rounds_batches += len(rounds)
         if n:
             self.stats.saturation_events += n
             if warn:
@@ -1136,7 +1232,8 @@ class QueryEngine:
                      * self._granule)
             args = (index.packed, index.il, jnp.zeros(qp, jnp.int32),
                     jnp.zeros(qp, jnp.int32), jnp.asarray(False))
-            key = AOTCache.key("label", self.backend, args, config=config)
+            key = AOTCache.key(f"label-v{OUTPUT_SCHEMA}", self.backend, args,
+                               config=config)
             fn = cache.load(key)
             if fn is None:
                 cache.store(key, self._label_phase.fallback, args)
@@ -1151,8 +1248,8 @@ class QueryEngine:
                     jnp.zeros((c,), jnp.int32),
                     jnp.full((c,), Q.FRESH_CUT, jnp.int32),
                     jnp.asarray(False))
-            key = AOTCache.key(f"coalesced-{c}", self.backend, args,
-                               config=config)
+            key = AOTCache.key(f"coalesced-{c}-v{OUTPUT_SCHEMA}",
+                               self.backend, args, config=config)
             fn = cache.load(key)
             if fn is None:
                 cache.store(key, self._coal_phases[c].fallback, args)

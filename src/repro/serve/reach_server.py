@@ -24,13 +24,13 @@ Two serving surfaces:
 ``examples/dynamic_reachability.py`` drives it end to end."""
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core.dbl import DBLIndex
 from repro.serve.engine import QueryEngine
+from repro.serve.spans import span, traced
 
 
 @dataclass
@@ -43,11 +43,6 @@ class ServeStats:
     rebuilds: int = 0
     delta_rebuilds: int = 0
     flushes: int = 0
-    query_s: float = 0.0
-    insert_s: float = 0.0
-    delete_s: float = 0.0
-    rebuild_s: float = 0.0
-    flush_s: float = 0.0
 
     def as_dict(self):
         rho = self.label_answered / max(self.queries, 1)
@@ -55,10 +50,7 @@ class ServeStats:
                 "inserts": self.inserts, "deletes": self.deletes,
                 "rebuilds": self.rebuilds,
                 "delta_rebuilds": self.delta_rebuilds,
-                "flushes": self.flushes,
-                "query_s": self.query_s, "insert_s": self.insert_s,
-                "delete_s": self.delete_s, "rebuild_s": self.rebuild_s,
-                "flush_s": self.flush_s}
+                "flushes": self.flushes}
 
 
 class ReachabilityServer:
@@ -142,51 +134,48 @@ class ReachabilityServer:
         return self.engine.index.is_dirty
 
     # ------------------------------------------------------- synchronous
+    @traced("server.query")
     def query(self, u, v) -> np.ndarray:
         self._maybe_rebuild()
-        t = time.perf_counter()
         ans, info = self.engine.query(np.asarray(u, np.int32),
                                       np.asarray(v, np.int32),
                                       return_stats=True)
-        self.stats.query_s += time.perf_counter() - t
         self.stats.queries += len(ans)
         self.stats.bfs_answered += info["n_bfs"]
         self.stats.label_answered += len(ans) - info["n_bfs"]
         return ans
 
     # --------------------------------------------------------- pipelined
+    @traced("server.submit")
     def submit(self, u, v):
         """Enqueue a query micro-batch against the current snapshot epoch;
         the label phase runs now, the BFS residue rides the next flush —
         possibly across intervening ``insert()`` calls."""
-        t = time.perf_counter()
         pend = self.engine.submit(self.engine.index,
                                   np.asarray(u, np.int32),
                                   np.asarray(v, np.int32))
         self._pending.append(pend)
-        self.stats.query_s += time.perf_counter() - t
         return pend
 
+    @traced("server.flush")
     def flush(self, *, consistency: str | None = None) -> list:
         """Resolve every outstanding micro-batch in one epoch-coalesced
         dispatch sequence; returns their answers in submission order.
         A scheduled lazy rebuild runs here, after the resolution."""
-        t = time.perf_counter()
         # flush BEFORE clearing the queue: if the engine rejects the
         # consistency mode, the submitted batches must stay enqueued
         pending = self._pending
         outs = self.engine.flush(pending, consistency=consistency)
         self._pending = []
-        self.stats.flush_s += time.perf_counter() - t
         self.stats.flushes += 1
         for pend, ans in zip(pending, outs):
-            nu = min(int(pend.n_unknown), pend.q)
             self.stats.queries += len(ans)
-            self.stats.bfs_answered += nu
-            self.stats.label_answered += len(ans) - nu
+            self.stats.bfs_answered += pend.nu
+            self.stats.label_answered += len(ans) - pend.nu
         self._maybe_rebuild()
         return outs
 
+    @traced("server.poll")
     def poll(self) -> bool:
         """Adaptive-flush poll point: give the engine's flush policy a
         chance to resolve the pipeline (a latency deadline must be able to
@@ -194,17 +183,18 @@ class ReachabilityServer:
         flushed.  No-op without a policy."""
         return self.engine.maybe_flush()
 
+    @traced("server.insert")
     def insert(self, src, dst):
         """Alg-3 insert: bumps the snapshot epoch; outstanding submits stay
         in flight and resolve with exact as-of-submit cutoffs at flush."""
-        t = time.perf_counter()
         idx = self.engine.insert(np.asarray(src, np.int32),
                                  np.asarray(dst, np.int32))
-        idx.packed.dl_in.block_until_ready()
-        self.stats.insert_s += time.perf_counter() - t
+        with span("server.wait_labels"):
+            idx.packed.dl_in.block_until_ready()
         self.stats.inserts += len(np.asarray(src))
 
     # ------------------------------------------------------ fully dynamic
+    @traced("server.delete")
     def delete(self, src, dst):
         """Tombstone matching live edges and go dirty — O(mask) work, no
         label recomputation.  Drains in-flight submits (see engine.delete),
@@ -212,28 +202,28 @@ class ReachabilityServer:
         policy threshold; the rebuild itself runs at the next flush/query
         boundary so the delete call returns immediately."""
         from repro.core import graph as G
-        t = time.perf_counter()
         idx = self.engine.delete(np.asarray(src, np.int32),
                                  np.asarray(dst, np.int32))
-        idx.graph.del_at.block_until_ready()
-        self.stats.delete_s += time.perf_counter() - t
+        with span("server.wait_graph"):
+            idx.graph.del_at.block_until_ready()
         self.stats.deletes += len(np.asarray(src))
         if self.rebuild_dead_ratio is not None and not self._rebuild_due:
-            dead = int(np.asarray(G.dead_edge_count(idx.graph)))
-            live = max(int(np.asarray(idx.graph.m)) - dead, 1)
+            with span("server.wait_graph"):
+                dead = int(np.asarray(G.dead_edge_count(idx.graph)))
+                live = max(int(np.asarray(idx.graph.m)) - dead, 1)
             if dead / live >= self.rebuild_dead_ratio:
                 self._rebuild_due = True
 
+    @traced("server.rebuild")
     def rebuild(self, **build_kw):
         """Rebuild labels over the live edge set now (clears dirty state;
         compacts tombstones; re-binds the engine, resolving in-flight
         submits first).  Defaults to the server's ``rebuild_mode`` policy
         ("auto": the index picks delta vs full by invalidation estimate)."""
         build_kw.setdefault("mode", self.rebuild_mode)
-        t = time.perf_counter()
         idx = self.engine.rebuild(**build_kw)
-        idx.packed.dl_in.block_until_ready()
-        self.stats.rebuild_s += time.perf_counter() - t
+        with span("server.wait_labels"):
+            idx.packed.dl_in.block_until_ready()
         self.stats.rebuilds += 1
         if self.engine.last_rebuild_info["mode"] == "delta":
             self.stats.delta_rebuilds += 1
@@ -286,6 +276,7 @@ def main(argv=None):
     vertex-sharded label planes (requires >= N devices)."""
     import argparse
     import json
+    import time
 
     import numpy as np
 

@@ -313,7 +313,10 @@ def test_delta_on_saturated_index_falls_back_to_full():
     assert bool(np.asarray(idx.saturated))
     idx = idx.delete_edges([5], [6])
     reb, info = idx.rebuild_info(mode="delta", max_iters=40)
+    rounds = info.pop("rounds")        # on the device: no wait here
     assert info == {"mode": "full", "reason": "saturated"}
+    # four plane fixpoints, each converged
+    assert rounds.shape == () and 0 < int(rounds) <= 4 * 40
     assert not bool(np.asarray(reb.saturated))   # honest full reconvergence
     assert_rebuild_equal(reb, idx.rebuild(mode="full", max_iters=40))
     _, info_auto = idx.rebuild_info(mode="auto", max_iters=40)

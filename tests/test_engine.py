@@ -503,6 +503,37 @@ def test_aot_rejects_meshed_layouts(tmp_path):
         eng.aot_warmup(eng.index, tmp_path)
 
 
+def test_aot_entry_under_an_older_output_schema_misses(tmp_path,
+                                                      monkeypatch):
+    """Entries exported before the coalesced program returned its level
+    counts were tagged ``label`` and ``coalesced-<chunk>``; a cache holding
+    them must miss (loading one would serve a one-output program), and the
+    engine then stores entries under its own tags."""
+    from repro.serve import engine as E
+    from repro.serve.aot import AOTCache
+    idx, _, _ = _power_law_index(n=64, m=160, m_extra=8, max_iters=40)
+    real_key = AOTCache.key
+
+    def old_key(tag, *a, **kw):
+        return real_key(tag.removesuffix(f"-v{E.OUTPUT_SCHEMA}"), *a, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(AOTCache, "key", staticmethod(old_key))
+        old = QueryEngine(idx, bfs_chunk=32, max_iters=40)
+        old.aot_warmup(idx, tmp_path)
+    assert old.aot_cache.stores > 0
+    new = QueryEngine(idx, bfs_chunk=32, max_iters=40)
+    new.aot_warmup(idx, tmp_path)
+    assert new.aot_cache.hits == 0
+    assert new.aot_cache.stores == old.aot_cache.stores
+    again = QueryEngine(idx, bfs_chunk=32, max_iters=40)
+    again.aot_warmup(idx, tmp_path)
+    assert again.aot_cache.hits == old.aot_cache.stores
+    u = np.arange(64, dtype=np.int32)
+    np.testing.assert_array_equal(again.run(idx, u, u[::-1]),
+                                  new.run(idx, u, u[::-1]))
+
+
 # ------------------------------- AOT cache-key completeness (PR 7)
 def test_aot_cache_key_includes_every_baked_knob(tmp_path):
     """Flipping any executable-baked engine knob must MISS the cache — a

@@ -27,3 +27,17 @@ def test_sharded_planes_differential():
         env=env, capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
     assert "SHARDED_PLANES_OK" in out.stdout
+
+
+@pytest.mark.slow
+def test_sharded_bfs_counts_levels_like_replicated():
+    """tests/distributed/run_bfs_counters.py: the sharded coalesced program
+    returns the replicated one's hit levels and level count, and a stream
+    leaves both engines with the same BFS counters."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"{ROOT / 'src'}:{ROOT}"
+    out = subprocess.run(
+        [sys.executable, str(ROOT / "tests/distributed/run_bfs_counters.py")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+    assert "BFS_COUNTERS_OK" in out.stdout

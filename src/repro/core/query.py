@@ -24,6 +24,7 @@ import numpy as np
 from . import bitset
 from .graph import Graph, edge_mask
 from .interval import il_negative
+from .pull import tail_live
 
 
 class PackedLabels(NamedTuple):
@@ -292,15 +293,18 @@ def _admit_plane(p: PackedLabels, u: jax.Array, v: jax.Array,
 
 
 #: dtypes selectable for the BFS frontier planes (``pruned_bfs`` and the
-#: sharded twin in ``core.planes``): "int8" is the default — the segment-max
-#: operand is (m_cap, Qc) at 1 byte/lane instead of the 4-byte int32 path,
-#: cutting the reduction's memory traffic 4x.  "int32" is kept as the wide
-#: reference path.  "packed" goes further: the query-lane axis packs into
-#: uint32 words (32 lanes/word) and the whole BFS — frontier, visited,
-#: admit, per-lane cutoffs, hits — runs on word planes via the bitset
-#: segment-OR algebra (replicated ``pruned_bfs`` only; the sharded twin
-#: rejects it).  All flavors produce bitwise-identical hits (parity-swept
-#: in tests/test_kernels.py).
+#: sharded twin in ``core.planes``).  "int8" and "int32" are lane-wise:
+#: they name the dtype of the push form's (m_cap, Qc) segment-max operand,
+#: "int8" (the default) at 1 byte a lane against int32's 4.  That operand
+#: exists only on the push paths: a foreign snapshot, ``query()``'s host
+#: reference, the sharded twin, and the pull form's insert tail.  A bound
+#: replicated engine pulls (``pulled_bfs``) on bool planes with no such
+#: operand, so there the two choose only the tail's dtype.  "packed" packs
+#: the query-lane axis into uint32 words (32 lanes/word) and the whole BFS
+#: — frontier, visited, admit, per-lane cutoffs, hits — runs on word planes
+#: via the bitset segment-OR algebra (replicated ``pruned_bfs`` only; the
+#: sharded twin rejects it; no pull form).  All flavors produce
+#: bitwise-identical hits (parity-swept in tests/test_kernels.py).
 FRONTIER_DTYPES = {"int8": jnp.int8, "int32": jnp.int32,
                    "packed": jnp.uint32}
 
@@ -369,6 +373,102 @@ def _pruned_bfs_packed(g, p, u, v, admit, m_cut, dl_on, il=None, il_on=None,
     return bitset.unpack(state[2], qc)
 
 
+def pulled_bfs(g, p, u, v, admit, m_cut, dl_on, layout, il=None, il_on=None,
+               *, n_cap, max_iters, ftype=jnp.int8, counted=False):
+    """The lane-wise BFS of ``pruned_bfs`` in pull form, over a
+    ``core.pull.InEdgeLayout`` of ``g``: each level gathers, per in-degree
+    class, the frontier rows of every row's in-edge sources and ORs them
+    across the row's width — no scatter and no sort in the level loop.
+
+    The loop runs in the layout's row order.  Once per call, outside it
+    (``bfs.layout``): the admit plane is permuted into row order, the
+    lanes' endpoints are mapped through ``pos`` (dead lanes' sources stay
+    out of range), and, if the graph was tombstoned since the layout was
+    built, slots of dead edges are pointed at the layout's empty row.
+    The slots of ``layout.tail`` (edges inserted since the layout, and
+    any a re-layout left without a row) are relaxed in the push form under
+    ``bfs.tail``.  Frontiers, hits, level counts and
+    the per-lane ``m_cut`` cutoffs are those of the push form, bit for
+    bit; ``ftype`` is the tail's segment-reduce dtype."""
+    qc = u.shape[0]
+    rows = layout.inv.shape[0]
+    zero_row = jnp.int32(rows - 1)
+    m_cap = g.src.shape[0]
+    with jax.named_scope("bfs.layout"):
+        if admit is None:
+            admit = _admit_plane(p, u, v, n_cap, dl_on, il, il_on)
+        elif admit.dtype != jnp.bool_:
+            admit = admit > 0
+        admit = admit[jnp.minimum(layout.inv, n_cap - 1)]      # (R, Qc)
+        pu = jnp.where((u >= 0) & (u < n_cap),
+                       layout.pos[jnp.clip(u, 0, n_cap - 1)], rows)
+        pv = layout.pos[v]
+
+        def drop_dead(srcs):
+            return tuple(
+                jnp.where(g.del_at[jnp.minimum(e, m_cap - 1)] > g.del_epoch,
+                          s, zero_row)
+                for s, e in zip(srcs, layout.eid))
+        srcs = jax.lax.cond(g.del_epoch > layout.del_epoch, drop_dead,
+                            lambda s: s, layout.src)
+    n_pulled = sum(s.shape[1] for s in srcs)
+    n_tail = layout.tail.shape[0]
+    if n_tail:
+        with jax.named_scope("bfs.tail"):
+            te = layout.tail
+            tc = jnp.minimum(te, m_cap - 1)
+            t_live = tail_live(g, te)
+            t_src = layout.pos[g.src[tc]]
+            t_dst = layout.pos[g.dst[tc]]
+    frontier = jnp.arange(rows, dtype=jnp.int32)[:, None] == pu[None, :]
+    visited = frontier
+    hit = jnp.zeros((qc,), jnp.bool_)
+    lanes = jnp.arange(qc)
+
+    def cond(state):
+        frontier, _, hit, it = state[:4]
+        return jnp.logical_and(frontier.any(),
+                               jnp.logical_and(~hit.all(), it < max_iters))
+
+    def body(state):
+        frontier, visited, hit, it = state[:4]
+        parts = []
+        for s, e in zip(srcs, layout.eid):
+            with jax.named_scope("bfs.gather"):
+                rows_in = frontier[s]                   # (width, rows_c, Qc)
+                if m_cut is not None:
+                    rows_in &= e[:, :, None] < m_cut[None, None, :]
+            with jax.named_scope("bfs.reduce"):
+                parts.append(jnp.any(rows_in, axis=0))
+        with jax.named_scope("bfs.reduce"):
+            parts.append(jnp.zeros((rows - n_pulled, qc), jnp.bool_))
+            nxt = jnp.concatenate(parts, axis=0)
+        if n_tail:
+            with jax.named_scope("bfs.tail"):
+                contrib = frontier[t_src] & t_live[:, None]
+                if m_cut is not None:
+                    contrib &= te[:, None] < m_cut[None, :]
+                nxt |= jax.ops.segment_max(contrib.astype(ftype), t_dst,
+                                           num_segments=rows) > 0
+        with jax.named_scope("bfs.update"):
+            nxt = nxt & admit & ~visited & ~hit[None, :]
+            new_hit = nxt[pv, lanes]      # new: nxt was masked by ~hit
+            hit = hit | new_hit
+            visited = visited | nxt
+            if counted:
+                return (nxt, visited, hit, it + 1,
+                        jnp.where(new_hit, it + 1, state[4]))
+        return nxt, visited, hit, it + 1
+
+    state = (frontier, visited, hit, jnp.int32(0))
+    if counted:
+        state += (jnp.zeros((qc,), jnp.int32),)
+    state = jax.lax.while_loop(cond, body, state)
+    if counted:
+        return level_table(state[4], state[3])
+    return state[2]
+
+
 def level_table(hit_level: jax.Array, levels: jax.Array) -> jax.Array:
     """(Qc, 2) int32 — per lane, the level at which it hit (0: never) and
     the level at which it was answered: its hit level, else ``levels``,
@@ -398,7 +498,7 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
                il=None,
                *, n_cap: int, max_iters: int = 256,
                frontier_dtype: str = "int8",
-               counted: bool = False) -> jax.Array:
+               counted: bool = False, layout=None) -> jax.Array:
     """(Qc,) bool — resolve unknown queries by label-pruned BFS lanes.
 
     ``admit`` lets callers supply a precomputed (n_cap, Qc) admit plane
@@ -442,6 +542,10 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
     ``level_table``: row q is lane q's (hit level, answer level), nonzero
     in its first entry exactly when lane q hit.  ``split_counted`` turns
     it into ``(hit, hit_level, levels)``; the hits are the same bits.
+
+    ``layout`` (a ``core.pull.InEdgeLayout`` of ``g``) runs the same BFS
+    in pull form (``pulled_bfs``): same answers and level table, with no
+    scatter in the level loop.  Not with ``frontier_dtype="packed"``.
     """
     ftype = FRONTIER_DTYPES[frontier_dtype]
     qc = u.shape[0]
@@ -454,6 +558,13 @@ def pruned_bfs(g: Graph, p: PackedLabels, u: jax.Array, v: jax.Array,
         dl_on = (m_cut >= g.m) & clean
     il_on = None if (il is None or dl_clean is None) \
         else jnp.broadcast_to(clean, u.shape)
+    if layout is not None:
+        if frontier_dtype == "packed":
+            raise ValueError("the pull form runs lane-wise frontier planes; "
+                             "frontier_dtype='packed' has no layout path")
+        return pulled_bfs(g, p, u, v, admit, m_cut, dl_on, layout, il, il_on,
+                          n_cap=n_cap, max_iters=max_iters, ftype=ftype,
+                          counted=counted)
     if frontier_dtype == "packed":
         return _pruned_bfs_packed(g, p, u, v, admit, m_cut, dl_on, il, il_on,
                                   n_cap=n_cap, max_iters=max_iters,

@@ -34,6 +34,7 @@ import jax
 from jax import export as jexport
 
 from repro.core.graph import Graph
+from repro.core.pull import InEdgeLayout
 from repro.core.query import PackedLabels
 
 
@@ -51,7 +52,7 @@ def _ensure_serialization_registered():
     global _REGISTERED
     if _REGISTERED:
         return
-    for cls in (PackedLabels, Graph):
+    for cls in (PackedLabels, Graph, InEdgeLayout):
         try:
             jexport.register_namedtuple_serialization(
                 cls, serialized_name=f"repro.core.{cls.__name__}")

@@ -100,6 +100,7 @@ import numpy as np
 
 from repro.core import halo as HL
 from repro.core import planes as PL
+from repro.core import pull as PULL
 from repro.core import query as Q
 from repro.core import update as U
 from repro.core.propagate import check_halo_mode, check_plane_repr
@@ -123,8 +124,9 @@ FLUSH_POLICIES = (None, "deadline", "watermark")
 
 #: version of the query executables' outputs, part of their AOT cache tags
 #: (``aot_warmup``): an entry exported under an older output schema must
-#: miss, never load.  2: ``coalesced`` returns (answers, hit_level, levels)
-OUTPUT_SCHEMA = 2
+#: miss, never load.  2: ``coalesced`` returns (answers, hit_level, levels);
+#: 3: and the live edges its BFS relaxed through the in-edge layout's tail
+OUTPUT_SCHEMA = 3
 
 
 def select_backend(backend: str = "auto") -> str:
@@ -208,6 +210,15 @@ class EngineStats:
     insert_rounds: int = 0
     insert_rounds_batches: int = 0
     rebuild_rounds: int = 0
+    # the pull form of the BFS residue (replicated engines with lane-wise
+    # frontiers): ``bfs_layouts`` counts in-edge layouts built (at bind,
+    # re-bind and when the insert tail outgrows its share),
+    # ``bfs_pull_dispatches`` the residue dispatches that ran the pull, and
+    # ``bfs_tail_edges`` the live edges they relaxed through the layout's
+    # tail (the edges inserted since the layout), summed over dispatches
+    bfs_layouts: int = 0
+    bfs_pull_dispatches: int = 0
+    bfs_tail_edges: int = 0
     #: per-family prune attribution over every resolved lane: "dl" counts
     #: label positives (Lemma 1 + self-queries), "bl"/"il" count negative
     #: lanes charged to BL containment / interval containment (first
@@ -238,6 +249,9 @@ class EngineStats:
                 "insert_rounds": self.insert_rounds,
                 "insert_rounds_batches": self.insert_rounds_batches,
                 "rebuild_rounds": self.rebuild_rounds,
+                "bfs_layouts": self.bfs_layouts,
+                "bfs_pull_dispatches": self.bfs_pull_dispatches,
+                "bfs_tail_edges": self.bfs_tail_edges,
                 "prune_hits": dict(self.prune_hits)}
 
 
@@ -408,6 +422,13 @@ class QueryEngine:
         # setter so the re-bind does not build it a second time
         self._plan: PL.ShardPlan | None = None
         self._plan_override: PL.ShardPlan | None = None
+        # replicated layout with lane-wise frontiers: the bound lineage's
+        # in-edge layout, over its first ``_m_layout`` edge slots, which
+        # the residue BFS pulls over (``core.pull``), and the room its
+        # tail leaves for inserted edges; None elsewhere
+        self._layout: PULL.InEdgeLayout | None = None
+        self._m_layout = 0
+        self._tail_room = 0
         # batch shapes are padded to this granule so a serving stream with
         # varying batch sizes maps onto a handful of compiled shapes
         self._granule = math.lcm(self.q_block, self.bfs_chunk)
@@ -474,10 +495,38 @@ class QueryEngine:
             with span("engine.wait_bind"):
                 self.epoch = int(np.asarray(idx.epoch))
                 self._m_now = int(idx.graph.m)
+            if self.vertex_mesh is None and self.frontier_dtype != "packed":
+                self._lay_out()
         else:
             self.epoch = 0
             self._m_now = 0
             self._plan = None
+            self._layout = None
+
+    def _lay_out(self):
+        """Build the bound index's in-edge layout (a device program that
+        reads back a few counts), keeping the shapes of the one it replaces
+        when the in-degrees still fit them."""
+        with span("engine.wait_layout"):
+            self._layout, self._tail_room = PULL.build(
+                self._index.graph, self._m_now, self._index.n_cap,
+                like=self._layout)
+        self._m_layout = self._m_now
+        self.stats.bfs_layouts += 1
+
+    def _pull_layout(self) -> PULL.InEdgeLayout:
+        """The in-edge layout a residue dispatch on the bound index pulls
+        over.  Edges inserted since the layout ride its tail while they fit
+        its room; past that the index is laid out anew.  The tail has one
+        capacity, so a chunk compiles two programs at most, both warmed:
+        without the tail (nothing inserted or left over) and with it."""
+        n_new = self._m_now - self._m_layout
+        if n_new > self._tail_room:
+            self._lay_out()
+            n_new = 0
+        if n_new == 0 and self._tail_room == self._layout.tail.shape[0]:
+            return PULL.without_tail(self._layout)
+        return self._layout
 
     def _drain_inflight(self):
         """Resolve every unresolved submit of the CURRENT lineage (with its
@@ -594,16 +643,18 @@ class QueryEngine:
             answers = verd == jnp.int8(1)
             return answers, order, u_c, v_c, n_unknown, counts
 
-        def residue_outputs(verd, live_lane, need, table):
-            """The coalesced phase's three outputs (every layout's), from
-            the re-check verdicts and the BFS's level table."""
+        def residue_outputs(verd, live_lane, need, table, tail_edges):
+            """The coalesced phase's four outputs (every layout's), from
+            the re-check verdicts, the BFS's level table and the live edges
+            it relaxed through the in-edge layout's tail."""
             hit, hit_level, levels = Q.split_counted(table)
             answers = ((verd == jnp.int8(1)) & live_lane) | hit
-            return answers, jnp.where(need, hit_level, -1), levels
+            return (answers, jnp.where(need, hit_level, -1), levels,
+                    tail_edges)
 
         def make_coalesced_phase(chunk: int):
             def coalesced(g: Q.Graph, p: Q.PackedLabels, il, uu, vv, m_cut,
-                          d_stale):
+                          d_stale, layout=None):
                 """One (chunk,)-shaped epoch-coalesced residue dispatch.
 
                 Fuses the monotone label re-check against the NEWEST labels
@@ -636,10 +687,16 @@ class QueryEngine:
                 keep it without an edge-count gate — and threads into the
                 residue BFS admit planes under the tombstone-clean gate.
 
-                Returns ``(answers, hit_level, levels)``: the (chunk,) bool
-                answers, the BFS's per-lane hit level (``split_counted``;
-                -1 on lanes that did not ride the BFS: padding and lanes
-                the re-check answered) and its level count."""
+                ``layout`` is the bound lineage's in-edge layout
+                (``core.pull``): the BFS then runs in pull form, with the
+                edges inserted since the layout relaxed through its tail.
+                A foreign snapshot has none and runs the push form.
+
+                Returns ``(answers, hit_level, levels, tail_edges)``: the
+                (chunk,) bool answers, the BFS's per-lane hit level
+                (``split_counted``; -1 on lanes that did not ride the BFS:
+                padding and lanes the re-check answered), its level count,
+                and the live edges in the layout's tail (0 without one)."""
                 n_cap = p.dl_in.shape[0]
                 live_lane = uu < jnp.int32(n_cap)
                 uu_safe = jnp.minimum(uu, jnp.int32(n_cap - 1))
@@ -669,8 +726,11 @@ class QueryEngine:
                 table = Q.pruned_bfs(g, p, uu2, vv, admit, m_cut, ~d_stale,
                                      il, n_cap=n_cap, max_iters=max_iters,
                                      frontier_dtype=frontier_dtype,
-                                     counted=True)
-                return residue_outputs(verd, live_lane, need, table)
+                                     counted=True, layout=layout)
+                tail_edges = jnp.int32(0) if layout is None else jnp.sum(
+                    PULL.tail_live(g, layout.tail), dtype=jnp.int32)
+                return residue_outputs(verd, live_lane, need, table,
+                                       tail_edges)
             return coalesced
 
         def make_coalesced_sharded(chunk: int):
@@ -712,7 +772,8 @@ class QueryEngine:
                     plan, p, rows, uu2, vv, edge_mask(g), m_cut, g.m,
                     ~d_stale, max_iters=max_iters,
                     frontier_dtype=frontier_dtype)
-                return residue_outputs(verd, live_lane, need, table)
+                return residue_outputs(verd, live_lane, need, table,
+                                       jnp.int32(0))
             return coalesced
 
         if vertex_mesh is not None:
@@ -755,14 +816,26 @@ class QueryEngine:
             donate_argnums=(0,) if self.donate else ())
 
     def _coalesced_extra_args(self) -> tuple:
-        """Trailing operands for a coalesced-phase call: the vertex-sharded
-        layout threads its plan's routing arrays through (so the compiled
-        executable survives plan rebuilds); replicated has none."""
+        """Trailing operands for a coalesced-phase call on the bound
+        index: the vertex-sharded layout threads its plan's routing arrays
+        through (so the compiled executable survives plan rebuilds); the
+        replicated one its in-edge layout, when it pulls."""
         if self.vertex_mesh is None:
-            return ()
+            return () if self._layout is None else (self._pull_layout(),)
         dp = self._plan.fwd
         return (dp.e_slot, dp.e_recv, dp.e_gid, dp.e_valid, dp.h_send,
                 dp.h_valid, dp.e_start, dp.e_tail)
+
+    def _warm_extra_args(self, index: DBLIndex) -> list[tuple]:
+        """Every set of trailing operands a coalesced phase on ``index``
+        is called with: a foreign index (replicated layout only) runs the
+        push form, with none; a pulling engine's bound index its layout
+        both without and with the tail."""
+        if self.vertex_mesh is None and index is not self._index:
+            return [()]
+        if self._layout is None:
+            return [self._coalesced_extra_args()]
+        return [(PULL.without_tail(self._layout),), (self._layout,)]
 
     def _chunk_buckets(self):
         sizes, c = [], 16
@@ -951,6 +1024,7 @@ class QueryEngine:
             fn = self._coal_phases[chunk]
             d_stale = jnp.asarray(index.dirty_flag)
             extra = self._coalesced_extra_args() if engine_group else ()
+            pulled = engine_group and self._layout is not None
             if self.vertex_mesh is not None and not engine_group:
                 raise ValueError(
                     "vertex-sharded engines resolve only batches submitted "
@@ -964,6 +1038,7 @@ class QueryEngine:
                                     jnp.asarray(cuts[start:start + chunk]),
                                     d_stale, *extra))
                     self.stats.bfs_dispatches += 1
+                    self.stats.bfs_pull_dispatches += pulled
         # all chunks are enqueued before this one transfer waits for them;
         # the label phase's outputs ride along (long since computed)
         with span("engine.wait_hits"):
@@ -972,7 +1047,7 @@ class QueryEngine:
         with span("engine.scatter"):
             hits_all = np.zeros(0, np.bool_)
             if parts:
-                hits_all = np.concatenate([a for a, _, _ in parts])[:total]
+                hits_all = np.concatenate([a for a, *_ in parts])[:total]
             self._count_levels(parts, chunk)
             off = 0
             for (i, p, nu), (answers, order, counts) in zip(infos, labels):
@@ -1001,10 +1076,12 @@ class QueryEngine:
                     ph["bfs"] += nu
 
     def _count_levels(self, parts, chunk: int):
-        """Add the coalesced dispatches' level counts (host copies of
-        their ``(answers, hit_level, levels)``) to the BFS counters."""
+        """Add the coalesced dispatches' level counts and tail edges (host
+        copies of their ``(answers, hit_level, levels, tail_edges)``) to
+        the BFS counters."""
         st = self.stats
-        for _, hit_level, levels in parts:
+        for _, hit_level, levels, tail_edges in parts:
+            st.bfs_tail_edges += int(tail_edges)
             levels = int(levels)
             rode = hit_level[hit_level >= 0]
             st.bfs_levels += levels
@@ -1243,18 +1320,19 @@ class QueryEngine:
             c = self._bucket_for(chunk)
             if not isinstance(self._coal_phases[c], ShapeDispatcher):
                 self._coal_phases[c] = ShapeDispatcher(self._coal_phases[c])
-            args = (index.graph, index.packed, index.il,
-                    jnp.full((c,), n_cap, jnp.int32),
-                    jnp.zeros((c,), jnp.int32),
-                    jnp.full((c,), Q.FRESH_CUT, jnp.int32),
-                    jnp.asarray(False))
-            key = AOTCache.key(f"coalesced-{c}-v{OUTPUT_SCHEMA}",
-                               self.backend, args, config=config)
-            fn = cache.load(key)
-            if fn is None:
-                cache.store(key, self._coal_phases[c].fallback, args)
-            else:
-                self._coal_phases[c].add(args, fn)
+            for extra in self._warm_extra_args(index):
+                args = (index.graph, index.packed, index.il,
+                        jnp.full((c,), n_cap, jnp.int32),
+                        jnp.zeros((c,), jnp.int32),
+                        jnp.full((c,), Q.FRESH_CUT, jnp.int32),
+                        jnp.asarray(False), *extra)
+                key = AOTCache.key(f"coalesced-{c}-v{OUTPUT_SCHEMA}",
+                                   self.backend, args, config=config)
+                fn = cache.load(key)
+                if fn is None:
+                    cache.store(key, self._coal_phases[c].fallback, args)
+                else:
+                    self._coal_phases[c].add(args, fn)
         return self
 
     # ------------------------------------------------------ introspection
@@ -1283,12 +1361,13 @@ class QueryEngine:
         d_clean = jnp.logical_and(jnp.asarray(index.dirty_flag), False)
         for chunk in (bfs_buckets or (self.bfs_chunk,)):
             c = self._bucket_for(chunk)
-            self._coal_phases[c](
-                index.graph, index.packed, index.il,
-                jnp.full((c,), n_cap, jnp.int32),
-                jnp.zeros((c,), jnp.int32),
-                jnp.full((c,), Q.FRESH_CUT, jnp.int32),
-                d_clean, *self._coalesced_extra_args())
+            for extra in self._warm_extra_args(index):
+                self._coal_phases[c](
+                    index.graph, index.packed, index.il,
+                    jnp.full((c,), n_cap, jnp.int32),
+                    jnp.zeros((c,), jnp.int32),
+                    jnp.full((c,), Q.FRESH_CUT, jnp.int32),
+                    d_clean, *extra)
         return self
 
 

@@ -720,3 +720,124 @@ def test_engine_streaming_il_falls_back_with_one_warning():
     with pytest.warns(StreamILFallbackWarning, match="grid kernel"):
         a2 = eng_s2.query(u, v)
     np.testing.assert_array_equal(a2, a)
+
+
+def test_pull_residue_stream_matches_packed_engine_and_counts():
+    """A bound replicated engine resolves its residue by the pull form
+    over its in-edge layout; a ``frontier_dtype="packed"`` engine keeps the
+    push form.  Through inserts (riding the layout's tail, and past it),
+    deletes and a rebuild, both answer every batch as the oracle does, and
+    the pull's counters say what ran: every residue dispatch pulled, tail
+    edges only after inserts, a layout at bind, at the rebuild, and when
+    the inserts outgrew the tail's room (1/16 of the laid-out edges)."""
+    idx, src, dst = _power_law_index(m_extra=160)
+    eng = QueryEngine(idx, bfs_chunk=64, max_iters=64)
+    ref = QueryEngine(idx, bfs_chunk=64, max_iters=64,
+                      frontier_dtype="packed", out_dtype="int32")
+    st = eng.stats
+    assert (st.bfs_layouts, ref.stats.bfs_layouts) == (1, 0)
+    rng = np.random.default_rng(17)
+    edges = [list(src), list(dst)]
+
+    def batch():
+        u = rng.integers(0, 256, 400).astype(np.int32)
+        v = rng.integers(0, 256, 400).astype(np.int32)
+        return u, v
+
+    def check(u, v, out, edges=edges, want=None):
+        np.testing.assert_array_equal(
+            out, ref.query(u, v) if want is None else want)
+        R = reach_oracle(256, np.asarray(edges[0], np.int32),
+                         np.asarray(edges[1], np.int32))
+        np.testing.assert_array_equal(out, R[u, v])
+
+    def insert(k):
+        ns = rng.integers(0, 256, k).astype(np.int32)
+        nd = rng.integers(0, 256, k).astype(np.int32)
+        eng.insert(ns, nd)
+        ref.insert(ns, nd)
+        edges[0] += ns.tolist()
+        edges[1] += nd.tolist()
+
+    u, v = batch()
+    check(u, v, eng.query(u, v))
+    assert st.bfs_dispatches > 0 and st.bfs_tail_edges == 0
+    # a batch submitted before an insert keeps its snapshot (cutoffs)
+    u0, v0 = batch()
+    old = [list(edges[0]), list(edges[1])]
+    pend, pend_ref = (e.submit(e.index, u0, v0) for e in (eng, ref))
+    insert(8)                     # within the tail's room, 80 slots
+    check(u0, v0, eng.flush([pend])[0], old, ref.flush([pend_ref])[0])
+    u, v = batch()
+    check(u, v, eng.query(u, v))
+    assert st.bfs_tail_edges > 0 and st.bfs_layouts == 1
+    kill = rng.choice(len(edges[0]), 30, replace=False)
+    ds = np.asarray(edges[0], np.int32)[kill]
+    dd = np.asarray(edges[1], np.int32)[kill]
+    eng.delete(ds, dd)
+    ref.delete(ds, dd)
+    dead = set(zip(ds.tolist(), dd.tolist()))
+    keep = [e not in dead for e in zip(*edges)]
+    edges[0] = [s for s, k in zip(edges[0], keep) if k]
+    edges[1] = [d for d, k in zip(edges[1], keep) if k]
+    u, v = batch()
+    check(u, v, eng.query(u, v))
+    eng.rebuild(mode="full")
+    ref.rebuild(mode="full")
+    assert st.bfs_layouts == 2
+    tail = st.bfs_tail_edges
+    u, v = batch()
+    check(u, v, eng.query(u, v))
+    assert st.bfs_tail_edges == tail, "a rebuilt layout holds every edge"
+    insert(100)                   # past the tail's room: a re-layout
+    u, v = batch()
+    check(u, v, eng.query(u, v))
+    assert st.bfs_layouts == 3 and st.bfs_tail_edges == tail
+    assert st.bfs_pull_dispatches == st.bfs_dispatches
+    assert ref.stats.bfs_pull_dispatches == 0
+    assert ref.stats.bfs_dispatches > 0
+
+
+def test_pull_insert_stream_compiles_nothing_after_warmup():
+    """Inserts of every size between flushes ride the in-edge layout's
+    tail, whose length is fixed, so the warmed coalesced programs (each
+    chunk without and with the tail) serve the whole stream: the BFS
+    dispatch shapes stop growing at warm-up, through a rebuild after
+    deletions too, and the answers stay the oracle's."""
+    idx, src, dst = _power_law_index(m_extra=200)
+    eng = QueryEngine(idx, bfs_chunk=64, max_iters=64)
+    eng.warmup(idx, batch_sizes=(400,), bfs_buckets=eng._chunk_buckets())
+    warm = eng.dispatch_shape_counts()["bfs"]
+    assert warm == 2 * len(eng._chunk_buckets())
+    rng = np.random.default_rng(23)
+    edges = [list(src), list(dst)]
+
+    def check():
+        u = rng.integers(0, 256, 400).astype(np.int32)
+        v = rng.integers(0, 256, 400).astype(np.int32)
+        R = reach_oracle(256, np.asarray(edges[0], np.int32),
+                         np.asarray(edges[1], np.int32))
+        np.testing.assert_array_equal(eng.query(u, v), R[u, v])
+
+    check()
+    for k in (1, 2, 3, 5, 9, 17, 30):          # 67 edges, room 80
+        ns = rng.integers(0, 256, k).astype(np.int32)
+        nd = rng.integers(0, 256, k).astype(np.int32)
+        eng.insert(ns, nd)
+        edges[0] += ns.tolist()
+        edges[1] += nd.tolist()
+        check()
+        assert eng.dispatch_shape_counts()["bfs"] == warm
+    assert eng.stats.bfs_tail_edges > 0 and eng.stats.bfs_layouts == 1
+    kill = rng.choice(len(edges[0]), 40, replace=False)
+    ds = np.asarray(edges[0], np.int32)[kill]
+    dd = np.asarray(edges[1], np.int32)[kill]
+    eng.delete(ds, dd)
+    dead = set(zip(ds.tolist(), dd.tolist()))
+    keep = [e not in dead for e in zip(*edges)]
+    edges[0] = [s for s, k in zip(edges[0], keep) if k]
+    edges[1] = [d for d, k in zip(edges[1], keep) if k]
+    eng.rebuild(mode="full")
+    check()
+    assert eng.stats.bfs_layouts == 2
+    assert eng.dispatch_shape_counts()["bfs"] == warm

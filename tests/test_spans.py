@@ -38,6 +38,9 @@ NESTING = {
     "index.rebuild.wait_fixpoint": ("server.rebuild",),
     "engine.wait_rebuild": ("server.rebuild",),
     "engine.wait_bind": ("server.rebuild",),
+    # a re-bind lays out the index's in-edges; so does a flush whose
+    # inserts outgrew the layout's tail
+    "engine.wait_layout": ("server.rebuild",) + RESOLVE,
     "engine.wait_sat": RESOLVE,
     "server.wait_labels": ("server.insert", "server.rebuild"),
 }

@@ -4,7 +4,8 @@ Nothing here runs on a chip: each test lowers and compiles one kernel
 dispatch at the widths the engine serves the Wiki deployment with (k = k' =
 64, so W = 2 words; the label phase's q_block and batch; the coalesced
 residue's bfs_chunk buckets; n of the Wiki row of the paper's Table 2) for
-one device of a described ``v5e:2x2`` topology.  What the TPU compiler
+one device of a described ``v5e:2x2`` topology.  The BFS residue's level
+loop is pinned too: no scatter and no sort in it.  What the TPU compiler
 refuses — a block layout Mosaic cannot tile, a select it cannot lower, more
 VMEM than a kernel may hold — fails here, in interpret mode's blind spot.
 
@@ -12,6 +13,8 @@ The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports this
 file.  Keep these tests in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -108,3 +111,91 @@ def test_bfs_prune_compiles(one_chip, streaming):
                         *_cut_operands(one_chip, q))
     # the (n, Qc) int8 plane is the bulk of what the dispatch writes
     assert compiled.memory_analysis().output_size_in_bytes >= N_WIKI * q
+
+
+# ---------------------------------------------- the residue's level loop
+_CALLEE = re.compile(r"\b(?:calls|to_apply|body|condition|branch_computations)"
+                     r"=\{?([%\w.\-, ]+)\}?")
+_HEAD = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) ")
+_OP = re.compile(r"= \S+ (scatter|sort)\(")
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _HEAD.match(line)
+        if m and line.rstrip().endswith("{"):
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+    return comps
+
+
+def _loop_ops(hlo: str) -> list[str]:
+    """Every ``scatter`` and ``sort`` instruction that a ``while`` body of
+    the compiled module runs, directly or through the computations it
+    calls (fusions, reducers, nested loops)."""
+    comps = _computations(hlo)
+    todo = [name for lines in comps.values() for line in lines
+            if " while(" in line
+            for name in re.findall(r"body=(%[\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for group in _CALLEE.findall(line):
+                todo += [c.strip() for c in group.split(",")]
+    return [line for name in seen for line in comps[name] if _OP.search(line)]
+
+
+@pytest.fixture(scope="module")
+def residue_layout():
+    """An in-edge layout of a Wiki-like graph at 1/32 of its size: the
+    shapes of the tables the engine's ``coalesced`` program pulls over."""
+    from repro.core import pull
+    from repro.core.graph import make_graph
+    from repro.graphs.generators import dag_like
+    n, m = 2 ** 16, 2 ** 17
+    src, dst = dag_like(n, m, seed=2101, back_frac=0.05)
+    return n, m, pull.build(make_graph(src, dst, n, m_cap=m), m, n)[0]
+
+
+@pytest.mark.parametrize("tail", [0, 1024], ids=["no_insert", "tail"])
+def test_coalesced_level_loop_has_no_scatter(one_chip, residue_layout, tail):
+    """The replicated engine's ``coalesced`` program, compiled for a v5e:
+    its BFS level loop pulls, so no ``scatter`` and no ``sort`` runs in
+    it, except in the push-form relaxation of the layout's insert tail
+    (``bfs.tail``), which a no-insert index does not have."""
+    from repro.core.graph import Graph
+    from repro.core.pull import InEdgeLayout
+    from repro.serve.engine import QueryEngine
+    n, m, lay = residue_layout
+    i32 = jnp.int32
+    edges = _spec(one_chip, (m,), i32)
+    g = Graph(edges, edges, _spec(one_chip, (), i32),
+              _spec(one_chip, (), i32), edges, _spec(one_chip, (), i32))
+    plane = _spec(one_chip, (n, W), jnp.uint32)
+    p = PackedLabels(plane, plane, plane, plane)
+    layout = InEdgeLayout(
+        _spec(one_chip, lay.pos.shape, i32),
+        _spec(one_chip, lay.inv.shape, i32),
+        tuple(_spec(one_chip, t.shape, i32) for t in lay.src),
+        tuple(_spec(one_chip, t.shape, i32) for t in lay.eid),
+        _spec(one_chip, (), i32), _spec(one_chip, (tail,), i32))
+    lanes = _spec(one_chip, (BFS_CHUNK,), i32)
+    eng = QueryEngine(backend="jnp", bfs_chunk=BFS_CHUNK)
+    hlo = eng._coal_phases[BFS_CHUNK].lower(
+        g, p, None, lanes, lanes, lanes, _spec(one_chip, (), jnp.bool_),
+        layout).compile().as_text()
+    ops = _loop_ops(hlo)
+    assert all("bfs.tail" in op for op in ops), [
+        op for op in ops if "bfs.tail" not in op]
+    assert bool(ops) == bool(tail), ops
+    # the push form this replaces scatters in its loop: the check sees it
+    push = _loop_ops(eng._coal_phases[BFS_CHUNK].lower(
+        g, p, None, lanes, lanes, lanes,
+        _spec(one_chip, (), jnp.bool_)).compile().as_text())
+    assert "scatter" in {_OP.search(op).group(1) for op in push}

@@ -3,8 +3,12 @@ schedule out, made from the seed before any window opens.
 
 Two loops, as the mix's ``loop`` says:
 
-- ``"closed"``: one client sends a batch of ``batch`` pairs, waits for its
-  answers, and sends the next.  The schedule is a list of batches.
+- ``"closed"``: one client repeats the mix's ``cycle`` (a list of steps,
+  each ``"batch"``, ``"insert"``, ``"delete"`` or ``"rebuild"``; by
+  default one batch) until ``max_batches`` batches are drawn.  A batch is
+  ``batch`` pairs it sends and waits for; an update is a call it waits
+  for between batches: ``insert_batch`` new edges, ``delete_batch`` live
+  edges, or ``rebuild()``.  ``warm_cycles`` cycles' updates run in set-up.
 - ``"open"``: queries arrive one by one at ``query_rate`` per second; an
   arrival joins the next micro-batch (at most ``batch_cap`` pairs).  Insert
   batches of ``insert_batch`` new edges arrive at ``insert_rate`` per
@@ -24,22 +28,25 @@ sees is fixed by the schedule: the generator replays the updates on an
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from itertools import groupby
 
 import numpy as np
 
 from bench.reference import EdgeLog, walk_targets
 
 LOOPS = ("closed", "open")
+UPDATES = ("insert", "delete", "rebuild")
 
 
 @dataclass
 class Op:
-    """One update of an open-loop schedule (or of the set-up)."""
+    """One update of a schedule (or of the set-up)."""
     kind: str                 # "insert" | "delete" | "rebuild"
-    due: float                # seconds after the window opens
+    due: float                # open loop: seconds after the window opens
     src: np.ndarray | None = None
     dst: np.ndarray | None = None
     version: int = 0          # the EdgeLog version this op produces
+    after: int = 0            # closed loop: batches answered before it runs
 
 
 @dataclass
@@ -50,7 +57,7 @@ class Schedule:
     due: np.ndarray | None    # (Q,) float64 due times (open loop)
     version: np.ndarray       # (Q,) int64 EdgeLog version each query sees
     batch: int                # closed: pairs per batch; open: micro-batch cap
-    ops: list = field(default_factory=list)       # window updates, due order
+    ops: list = field(default_factory=list)       # window updates, in order
     warm_ops: list = field(default_factory=list)  # updates run in set-up
     warm_u: np.ndarray | None = None              # one batch for set-up
     warm_v: np.ndarray | None = None
@@ -64,8 +71,7 @@ def _pairs(log: EdgeLog, count: int, rng, walk_share: float, max_len: int):
     v = rng.integers(0, n, count).astype(np.int32)
     walk = rng.permutation(count) < round(walk_share * count)
     if walk.any():
-        src, dst = log.snapshot()
-        v[walk] = walk_targets(src, dst, n, u[walk], rng, max_len)
+        v[walk] = walk_targets(*log.out_csr(), u[walk], rng, max_len)
     return u, v
 
 
@@ -91,16 +97,76 @@ def _due_times(count: int, seconds: float, rng, spacing: float = 0.0):
     return np.sort(rng.random(count)) * room + np.arange(count) * spacing
 
 
+def _draw(op: Op, mix: dict, log: EdgeLog, rng) -> Op:
+    """Draw ``op``'s edges over the graph live now and replay it on
+    ``log``."""
+    if op.kind == "insert":
+        op.src, op.dst = _new_edges(log.n, mix["insert_batch"], rng)
+        op.version = log.insert(op.src, op.dst)
+    elif op.kind == "delete":
+        op.src, op.dst = _live_pick(log, mix["delete_batch"], rng)
+        op.version = log.delete(op.src, op.dst)
+    else:
+        op.version = log.version
+    return op
+
+
+def _closed_steps(mix: dict) -> list:
+    """The window's steps of a closed mix: its ``cycle`` repeated until
+    ``max_batches`` batches, ending with the last of them."""
+    cycle = mix.get("cycle", ["batch"])
+    steps = cycle * -(-mix["max_batches"] // cycle.count("batch"))
+    last = [i for i, k in enumerate(steps) if k == "batch"][
+        mix["max_batches"] - 1]
+    return steps[:last + 1]
+
+
+def _closed(mix: dict, log: EdgeLog, rng, walk) -> Schedule:
+    """The closed loop: ``warm_cycles`` cycles' updates for the set-up,
+    then ``_closed_steps``, each run of consecutive batches drawn in one go
+    over the graph live when it is sent."""
+    b, cycle = mix["batch"], mix.get("cycle", ["batch"])
+    warm = [_draw(Op(k, 0.0), mix, log, rng)
+            for _ in range(mix.get("warm_cycles", 0))
+            for k in cycle if k != "batch"]
+    wu, wv = _pairs(log, b, rng, *walk)
+    us, vs, vers, ops, sent = [], [], [], [], 0
+    for kind, run in groupby(_closed_steps(mix)):
+        count = len(list(run))
+        if kind == "batch":
+            u, v = _pairs(log, count * b, rng, *walk)
+            us.append(u)
+            vs.append(v)
+            vers.append(np.full(count * b, log.version, np.int64))
+            sent += count
+        else:
+            ops += [_draw(Op(kind, 0.0, after=sent), mix, log, rng)
+                    for _ in range(count)]
+    return Schedule("closed", np.concatenate(us), np.concatenate(vs), None,
+                    np.concatenate(vers), b, ops, warm, warm_u=wu, warm_v=wv)
+
+
 def check(mix: dict):
     if mix.get("loop") not in LOOPS:
         raise ValueError(f"mix loop {mix.get('loop')!r} is not one of "
                          f"{LOOPS}")
+    cycle = mix.get("cycle", ["batch"])
+    bad = set(cycle) - {"batch", *UPDATES}
+    if bad or "batch" not in cycle:
+        raise ValueError(f"mix cycle {cycle} needs a batch, and its other "
+                         f"steps must be among {UPDATES}")
 
 
 def max_inserted_edges(mix: dict, seconds: float) -> int:
     """Edges a window of ``seconds`` inserts, set-up included: what the
-    index's edge capacity must leave room for."""
-    if mix["loop"] == "closed" or not mix.get("insert_rate"):
+    index's edge capacity must leave room for.  A closed mix inserts the
+    same whatever the window's length: every insert of its schedule."""
+    if mix["loop"] == "closed":
+        inserts = mix.get("warm_cycles", 0) \
+            * mix.get("cycle", []).count("insert") \
+            + _closed_steps(mix).count("insert")
+        return inserts * mix.get("insert_batch", 0)
+    if not mix.get("insert_rate"):
         return 0
     warm = mix.get("warm_cycles", 0)
     return (round(mix["insert_rate"] * seconds) + warm) * mix["insert_batch"]
@@ -110,28 +176,19 @@ def make(mix: dict, log: EdgeLog, rng, seconds: float) -> Schedule:
     """The whole schedule of one run.  ``log`` holds the initial graph and
     is advanced through the set-up and window updates, in due order."""
     check(mix)
-    n = log.n
     walk = mix.get("walk_share", 0.5), mix.get("walk_max_len", 8)
     if mix["loop"] == "closed":
-        b = mix["batch"]
-        wu, wv = _pairs(log, b, rng, *walk)
-        count = mix["max_batches"] * b
-        u, v = _pairs(log, count, rng, *walk)
-        return Schedule("closed", u, v, None,
-                        np.full(count, log.version, np.int64), b,
-                        warm_u=wu, warm_v=wv)
+        return _closed(mix, log, rng, walk)
 
     # set-up: the update kinds the window uses, ``warm_cycles`` times each,
     # so every program they run is compiled before the window opens
     warm = []
     for _ in range(mix.get("warm_cycles", 0)):
         if mix.get("insert_rate"):
-            s, d = _new_edges(n, mix["insert_batch"], rng)
-            warm.append(Op("insert", 0.0, s, d, log.insert(s, d)))
+            warm.append(_draw(Op("insert", 0.0), mix, log, rng))
         if mix.get("delete_rate"):
-            s, d = _live_pick(log, mix["delete_batch"], rng)
-            warm.append(Op("delete", 0.0, s, d, log.delete(s, d)))
-            warm.append(Op("rebuild", 0.0, version=log.version))
+            warm.append(_draw(Op("delete", 0.0), mix, log, rng))
+            warm.append(_draw(Op("rebuild", 0.0), mix, log, rng))
     wu, wv = _pairs(log, mix["batch_cap"], rng, *walk)
 
     ops = []
@@ -161,14 +218,7 @@ def make(mix: dict, log: EdgeLog, rng, seconds: float) -> Schedule:
             lo = hi
         if op is None:
             break
-        if op.kind == "insert":
-            op.src, op.dst = _new_edges(n, mix["insert_batch"], rng)
-            op.version = log.insert(op.src, op.dst)
-        elif op.kind == "delete":
-            op.src, op.dst = _live_pick(log, mix["delete_batch"], rng)
-            op.version = log.delete(op.src, op.dst)
-        else:
-            op.version = log.version
+        _draw(op, mix, log, rng)
     cat = (lambda xs, dt: np.concatenate(xs) if xs
            else np.zeros(0, dt))
     return Schedule("open", cat(us, np.int32), cat(vs, np.int32), q_due,

@@ -2,7 +2,8 @@
 against the plain reference, and the record the metric readers read.
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` pairs a
-configuration (``bench/configs/<config>.json``) with a traffic mix
+configuration (``bench/configs/<config>.json``, whose ``generator.name``
+names a graph generator ``bench/graphs/<name>.py``) with a traffic mix
 (``bench/traffic/<mix>.json``, whose ``kind`` names a generator module
 ``bench/generators/<kind>.py``), and each metric the cell reports is read
 by ``bench/metrics/<metric>.py``.  Nothing here names a cell.
@@ -17,7 +18,12 @@ The record handed to the metric readers (``read(rec) -> float | None``):
   flush, a policy flush inside ``submit`` or ``poll``, or the drain at the
   start of a delete or a rebuild, which is stamped after the rebuild);
 - ``ops``: the window's updates, each ``{kind, due, start, end, info}``
-  in seconds after the opening;
+  in seconds after the opening (closed loop: ``due`` is when the window
+  reached the update, so ``end - due`` is the call's own time);
+- ``batch_s``: (B, 2) submit and answer times of the window's answered
+  batches, in seconds after the opening;
+- ``window_compiles``: XLA compilations (cache loads included) inside
+  the window;
 - ``late_s``: per issued item, how late the generator issued it;
 - ``engine``: the engine's counters over the window (``EngineStats``);
 - ``trace``: the reduced device trace of a ``--trace 1`` run, else None.
@@ -63,6 +69,8 @@ def load_module(path: pathlib.Path):
     once per process under a name made from its path."""
     name = "bench_file_" + re.sub(r"\W", "_", str(path))
     if name not in sys.modules:
+        if not path.is_file():
+            raise FileNotFoundError(f"no file {path}")
         spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(spec)
         sys.modules[name] = mod
@@ -87,6 +95,13 @@ def find_cell(bm: dict, workload: str, root: pathlib.Path = ROOT):
 
 def generator(mix: dict, root: pathlib.Path = ROOT):
     return load_module(root / "bench" / "generators" / f"{mix['kind']}.py")
+
+
+def graph_generator(cfg: dict, root: pathlib.Path = ROOT):
+    """The module that makes a configuration's graph:
+    ``make(cfg, seed) -> (src, dst)``."""
+    return load_module(root / "bench" / "graphs"
+                       / f"{cfg['generator']['name']}.py")
 
 
 def cell_metrics(bm: dict, workload: str, trace: bool) -> list[dict]:
@@ -178,8 +193,10 @@ def warm_up(server, sched):
         server.flush()
 
 
-def make_data(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float):
-    """The graph and the whole schedule of one run, from the seed.
+def make_data(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float,
+              root: pathlib.Path = ROOT):
+    """The graph (``graph_generator``) and the whole schedule of one run,
+    from the seed.
 
     A configuration whose ``generator`` names a ``fixed_seed`` holds one
     graph, made from that number, and the run's seed only shuffles the
@@ -188,11 +205,9 @@ def make_data(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float):
     Without them, each seed draws its own graph and schedule.  Returns the
     initial (src, dst) to build from, the schedule and the edge log the
     reference reads."""
-    g = cfg["generator"]
-    fixed = g.get("fixed_seed")
-    src, dst = R.dag_like(
-        cfg["n"], cfg["m"], back_frac=g["back_frac"],
-        seed=fixed if fixed is not None
+    fixed = cfg["generator"].get("fixed_seed")
+    src, dst = graph_generator(cfg, root).make(
+        cfg, fixed if fixed is not None
         else int(rng_for(seed, 0).integers(2 ** 63)))
     edge_log = R.EdgeLog(cfg["n"], src, dst)
     t = time.perf_counter()
@@ -209,7 +224,7 @@ def make_data(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float):
 
 def set_up(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float,
            backend: str, *, control: bool = False,
-           edge_room: int | None = None):
+           edge_room: int | None = None, root: pathlib.Path = ROOT):
     """Everything before a window opens: the graph and the whole schedule
     (``make_data``), the index (built with ``check="raise"``), the server
     and its warm-up.  ``edge_room`` is the room for inserted edges, by
@@ -218,7 +233,8 @@ def set_up(cfg: dict, mix: dict, gen_mod, seed: int, seconds: float,
     import jax
     from repro.core.dbl import DBLIndex
     from repro.core.graph import make_graph
-    src, dst, sched, edge_log = make_data(cfg, mix, gen_mod, seed, seconds)
+    src, dst, sched, edge_log = make_data(cfg, mix, gen_mod, seed, seconds,
+                                          root)
     if edge_room is None:
         edge_room = gen_mod.max_inserted_edges(mix, MAX_SECONDS)
     m_cap = edge_capacity(cfg, edge_room)
@@ -301,18 +317,22 @@ class Window:
         self.server.flush()             # resolves nothing: all are answered
         self.queued = 0
 
-    def run_op(self, op):
+    def run_op(self, op, due: float | None = None):
+        """Run one update.  ``due`` is given in the closed loop: the moment
+        the window reached the update, which the client never runs late."""
         if op.kind == "insert":
             # inserts do not drain: these batches resolve at a later version
             for b in self.open:
                 b["spans"] = True
         t = self.now()
-        self.late.append(t - op.due)
-        self.late_due.append(op.due)
+        if due is None:
+            due = op.due
+            self.late.append(t - due)
+            self.late_due.append(due)
         with self.span(op.kind):
             info = run_op(self.server, op)
         end = self.now()
-        self.ops.append(dict(kind=op.kind, due=op.due, start=t, end=end,
+        self.ops.append(dict(kind=op.kind, due=due, start=t, end=end,
                              info=info))
         if op.kind != "insert":
             self.stamp(drained=True)
@@ -325,9 +345,19 @@ class Window:
                                                and op.kind != "rebuild")
 
     def closed(self):
+        """Batch after batch, each flushed before the next; the schedule's
+        updates run between them.  The step running at the close
+        completes and counts."""
         s, b = self.sched, self.sched.batch
+        ops = iter(s.ops)
+        op = next(ops, None)
         lo = 0
         while lo + b <= s.u.size:
+            while op is not None and op.after == lo // b:
+                self.run_op(op, due=self.now())
+                op = next(ops, None)
+                if self.ops[-1]["end"] >= self.seconds:
+                    return
             bt = self.submit(lo, lo + b, None)
             with self.span("flush"):
                 self.server.flush()
@@ -498,6 +528,7 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
     bm = benchmark(root)
     w, cfg, mix = find_cell(bm, workload, root)
     gen_mod = generator(mix, root)
+    graph_generator(cfg, root)      # a missing file fails before set-up
     if not 0 < seconds <= MAX_SECONDS:
         raise ValueError(f"--seconds {seconds} is not in (0, {MAX_SECONDS}]")
     devices = jax.devices() if devices is None else devices
@@ -505,7 +536,7 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
 
     # ---- set-up: data, index, server, warm-up
     server, sched, edge_log = set_up(cfg, mix, gen_mod, seed, seconds,
-                                     backend, control=control)
+                                     backend, control=control, root=root)
     eng = server.engine
     before = eng.stats.as_dict()
     shapes_before = eng.dispatch_shape_counts()
@@ -546,7 +577,9 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
                mix=mix, setup_s=setup_s, window_s=window_s,
                queries=int(sum(b["hi"] - b["lo"] for b in answered)),
                latency_s=latency, ops=win.ops, late_s=np.asarray(win.late),
-               engine=engine, trace=reduced,
+               batch_s=np.array([(b["submit"], b["done"]) for b in answered],
+                                np.float64).reshape(-1, 2),
+               window_compiles=compiles, engine=engine, trace=reduced,
                device_kind=devices[0].device_kind)
     log(f"window: {window_s:.3f} s, {rec['queries']} queries in "
         f"{len(win.batches)} batches, "

@@ -1,5 +1,6 @@
-"""The device's idle share in a cell whose end-to-end metric is a latency
-tail; the same reading as ``device_idle_pct``."""
+"""The device's idle share in a cell whose end-to-end metrics are the
+latencies of batches and updates; the same reading as
+``device_idle_pct``."""
 import pathlib
 
 from bench import harness

@@ -1,6 +1,7 @@
 """Median over the window's insert batches of the time from the batch's
 due time to the return of ``insert()``, which waits for the new labels
-(host clock)."""
+(host clock).  In the closed loop an insert is due when the window
+reaches it, so this is the call's own time."""
 import numpy as np
 
 

@@ -2,10 +2,10 @@
 plain reference the answers are checked against.
 
 Copied from the program (``repro.graphs.generators.dag_like`` and
-``chip_smoke.py``'s ``walk_targets``; ``host_reach`` is ``chip_smoke.py``'s
-per-pair BFS made bidirectional) so that a change to the program cannot
-change what the benchmark generates or how it judges.  Imports nothing of
-the program.
+``chip_smoke.py``'s ``walk_targets``, here over a ``csr`` given to it;
+``host_reach`` is ``chip_smoke.py``'s per-pair BFS made bidirectional) so
+that a change to the program cannot change what the benchmark generates or
+how it judges.  Imports nothing of the program.
 """
 from __future__ import annotations
 
@@ -41,10 +41,10 @@ def csr(src, dst, n: int):
     return np.cumsum(indptr), heads
 
 
-def walk_targets(src, dst, n: int, starts, rng, max_len: int = 8):
-    """End points of random walks of 1..max_len steps over (src, dst); a
-    walk that reaches a vertex with no out-edge stops there."""
-    indptr, heads = csr(src, dst, n)
+def walk_targets(indptr, heads, starts, rng, max_len: int = 8):
+    """End points of random walks of 1..max_len steps over the graph whose
+    out-edges ``csr`` gives; a walk that reaches a vertex with no out-edge
+    stops there."""
     cur = starts.copy()
     steps = rng.integers(1, max_len + 1, starts.size)
     for s in range(max_len):
@@ -107,6 +107,7 @@ class EdgeLog:
         self.ins = np.zeros(self.src.size, np.int64)
         self.dele = np.full(self.src.size, NEVER, np.int64)
         self.version = 0
+        self._by_src = None     # every edge's slot, stably sorted by source
 
     def live_mask(self, version: int | None = None):
         v = self.version if version is None else version
@@ -117,7 +118,24 @@ class EdgeLog:
         live = self.live_mask(version)
         return self.src[live], self.dst[live]
 
+    def out_csr(self):
+        """``csr`` of the edges live now, without a sort: the slots of
+        every edge the log holds, stably sorted by source once and kept so
+        as edges are inserted, less the dead ones."""
+        if self._by_src is None:
+            self._by_src = np.argsort(self.src, kind="stable")
+        order = self._by_src[self.live_mask()[self._by_src]]
+        indptr = np.zeros(self.n + 1, np.int64)
+        indptr[1:] = np.cumsum(np.bincount(self.src[order],
+                                           minlength=self.n))
+        return indptr, self.dst[order]
+
     def insert(self, s, d) -> int:
+        if self._by_src is not None:
+            s = np.asarray(s, np.int32)
+            new = np.argsort(s, kind="stable")
+            at = np.searchsorted(self.src[self._by_src], s[new], "right")
+            self._by_src = np.insert(self._by_src, at, self.src.size + new)
         self.version += 1
         self.src = np.concatenate([self.src, np.asarray(s, np.int32)])
         self.dst = np.concatenate([self.dst, np.asarray(d, np.int32)])

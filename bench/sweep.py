@@ -1,7 +1,7 @@
 """Find the highest rates the served path sustains for an open-loop mix,
 one kind of traffic at a time, in one process with one set-up.
 
-    python bench/sweep.py --workload email.churn --seed 5
+    python bench/sweep.py --workload email.steady --seed 5
 
 For each of the mix's streams alone (queries; insert batches; delete
 cycles of delete, ``dirty_s`` of dirty serving and a rebuild) it runs the
@@ -72,7 +72,7 @@ def sweep(workload: str, seed: int, *, backend: str = "pallas",
         round(r * ins_s) for r in ins_rates)
     # the same set-up as a cell's run, with room for the sweep's inserts
     server, warm, log = H.set_up(cfg, warm_mix, gen, seed, 1.0, backend,
-                                 edge_room=room)
+                                 edge_room=room, root=root)
     rng = H.rng_for(seed, 3)
     # the warm schedule's own window items: run them, so the log and the
     # server agree before the first step

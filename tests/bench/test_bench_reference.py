@@ -23,7 +23,7 @@ def test_walk_targets_are_reachable():
     rng = np.random.default_rng(6)
     n, src, dst = random_graph(rng, n_max=40, m_max=120)
     us = rng.integers(0, n, 200).astype(np.int32)
-    ws = R.walk_targets(src, dst, n, us, rng)
+    ws = R.walk_targets(*R.csr(src, dst, n), us, rng)
     assert reach_oracle(n, src, dst)[us, ws].all()
 
 
@@ -48,3 +48,49 @@ def test_edge_log_replays_versions():
     np.testing.assert_array_equal(log.reach(1, us, ws), [True, True, True])
     np.testing.assert_array_equal(log.reach(2, us, ws), [False, False, False])
     np.testing.assert_array_equal(log.reach(3, us, ws), [True, True, True])
+
+
+def test_out_csr_is_the_csr_of_the_live_graph():
+    """The edge log's kept order gives what ``csr`` of a snapshot does,
+    through inserts (also of edges it already holds) and deletes."""
+    rng = np.random.default_rng(8)
+    n = 50
+    log = R.EdgeLog(n, rng.integers(0, n, 300), rng.integers(0, n, 300))
+    for step in range(12):
+        indptr, heads = log.out_csr()
+        want = R.csr(*log.snapshot(), n)
+        np.testing.assert_array_equal(indptr, want[0])
+        np.testing.assert_array_equal(heads, want[1])
+        if step % 3 == 2:
+            src, dst = log.snapshot()
+            pick = rng.choice(src.size, 20, replace=False)
+            log.delete(src[pick], dst[pick])
+        else:
+            s = rng.integers(0, n, 40)
+            log.insert(s, rng.integers(0, n, 40))
+
+
+@pytest.mark.parametrize("n,m", [(3000, 6000), (265000, 420000)])
+def test_bow_tie_has_the_configured_components(n, m):
+    """``bench/graphs/bow_tie.py`` at the tiny and the full size of
+    ``email-euall``: m distinct edges without self-loops, its largest SCC
+    and WCC hold exactly the configured shares of vertices and edges, and
+    the degrees are heavy-tailed."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import connected_components
+    from bench import harness as H
+    cfg = dict(H.load_json(H.ROOT / "bench/configs/email-euall.json"),
+               n=n, m=m)
+    g = cfg["generator"]
+    src, dst = H.graph_generator(cfg).make(cfg, 11)
+    assert src.size == m and not (src == dst).any()
+    assert np.unique(src.astype(np.int64) * n + dst).size == m
+    a = csr_matrix((np.ones(m), (src, dst)), shape=(n, n))
+    for kind, share in (("strong", "scc"), ("weak", "wcc")):
+        _, label = connected_components(a, connection=kind)
+        big = label == np.bincount(label).argmax()
+        assert big.sum() == round(g[f"{share}_vertex_share"] * n)
+        assert (big[src] & big[dst]).sum() == round(g[f"{share}_edge_share"]
+                                                    * m)
+    assert np.bincount(src).max() > 50 * m / n
+    assert np.bincount(dst).max() > 20 * m / n

@@ -78,11 +78,17 @@ def _record(reduced):
                 ops=[dict(kind="insert", due=1.0, start=1.1, end=1.6),
                      dict(kind="insert", due=2.0, start=2.0, end=2.4),
                      dict(kind="delete", due=3.0, start=3.0, end=3.01),
-                     dict(kind="rebuild", due=4.0, start=4.0, end=5.5)],
+                     dict(kind="rebuild", due=4.0, start=4.0, end=5.5),
+                     dict(kind="rebuild", due=6.0, start=6.0, end=6.5),
+                     dict(kind="rebuild", due=7.0, start=7.0, end=7.4)],
                 late_s=np.linspace(0.0, 0.2, 101),
+                batch_s=np.array([[0.0, 0.2], [0.2, 0.3], [1.6, 1.9]]),
+                window_compiles=0,
                 engine={"queries": 20000,
                         "prune_hits": {"dl": 1, "bl": 1, "il": 0, "thm": 0,
-                                       "bfs": 5000}},
+                                       "bfs": 5000},
+                        "insert_rounds": 30, "insert_rounds_batches": 2,
+                        "rebuild_rounds": 36, "rebuilds": 3},
                 trace=reduced, config=cfg,
                 device_kind="TPU v5 lite")
 
@@ -109,8 +115,14 @@ def test_metric_readers_compute_what_they_say(reduced):
     assert H.read_metric("queries_per_s", rec) == 2000.0
     assert H.read_metric("query_p95_ms", rec) == pytest.approx(950.0)
     assert H.read_metric("insert_p50_ms", rec) == pytest.approx(500.0)
-    assert H.read_metric("rebuild_p50_ms", rec) == pytest.approx(1500.0)
+    assert H.read_metric("rebuild_mean_ms", rec) == pytest.approx(800.0)
     assert H.read_metric("bfs_lane_pct", rec) == pytest.approx(25.0)
     assert H.read_metric("gen_late_p95_ms", rec) == pytest.approx(190.0)
     assert H.read_metric("device_idle_pct.churn", rec) \
         == H.read_metric("device_idle_pct", rec)
+    assert H.read_metric("batch_p50_ms", rec) == pytest.approx(200.0)
+    assert H.read_metric("window_compiles", rec) == 0.0
+    assert H.read_metric("window_compiles", dict(rec, window_compiles=3)) \
+        == 3.0
+    assert H.read_metric("insert_rounds_per_batch", rec) == 15.0
+    assert H.read_metric("rebuild_rounds_per_rebuild", rec) == 12.0
